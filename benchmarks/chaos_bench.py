@@ -155,9 +155,11 @@ def _cell(point, victim, fault, *, b_nth=6, fault_seed=0,
                         terminal_codes=(0, 4), env=env,
                         ready_pattern=r"^LISTENING ",
                         log_path=os.path.join(td, "supervisor_A.log"))
+    # B is a pure wire peer: pinned off the device, so only A needs the chip
     b = SupervisedChild("B", _argv_for(b_base, b_inc0), policy=policy,
                         terminal_codes=(0, 4), env=env,
-                        log_path=os.path.join(td, "supervisor_B.log"))
+                        log_path=os.path.join(td, "supervisor_B.log"),
+                        needs_device=False)
     t0 = time.perf_counter()
     a.start()
     deadline = time.monotonic() + 60.0

@@ -126,6 +126,8 @@ def _open_loop_row(km, d_a, d, ladder, batches, copies, offered_rps,
 # ---------------------------------------------------------------------------
 
 def _spawn_server(args, env):
+    from repro.launch.supervisor import ensure_chip_free
+    ensure_chip_free("load bench server")
     p = subprocess.Popen(
         [sys.executable, "-m", "repro.launch.serve_kmeans"] + args,
         stdout=subprocess.PIPE, stderr=subprocess.STDOUT, text=True,
@@ -224,6 +226,10 @@ def run(quick: bool = False):
     else:
         kw = dict(n_train=1024, d=16, k=5, ladder=(32, 128), rows=24,
                   n_requests=64, copies=16, max_queue=8)
+    # the kill/restart leg first: its server needs the chip, which this
+    # process holds once the in-process legs below have touched JAX
+    with tempfile.TemporaryDirectory() as td:
+        chaos = _chaos_row(td, n_requests=6)
     km, d_a = _fit(kw["n_train"], kw["d"], kw["k"])
     batches = _stream(kw["n_requests"], kw["rows"], kw["d"], kw["k"], d_a)
     base = _closed_loop_rate(km, d_a, kw["d"], kw["ladder"],
@@ -235,8 +241,7 @@ def run(quick: bool = False):
         rows.append(_open_loop_row(km, d_a, kw["d"], kw["ladder"], batches,
                                    kw["copies"], mult * base,
                                    kw["max_queue"]))
-    with tempfile.TemporaryDirectory() as td:
-        rows.append(_chaos_row(td, n_requests=6))
+    rows.append(chaos)
     with open(BENCH_PATH, "w") as f:
         json.dump({"rows": rows,
                    "note": "Serving-plane load + chaos: open-loop offered "

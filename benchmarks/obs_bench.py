@@ -65,7 +65,7 @@ def _noop_ns_per_call(calls: int = 200_000) -> dict:
 
 
 def _fit_once(a, b, k, iters, bs):
-    cfg = KMeansConfig(k=k, iters=iters, seed=3, backend="pallas",
+    cfg = KMeansConfig(k=k, iters=iters, seed=3, backend="auto",
                        sparse=True, batch_size=bs, offline="pooled",
                        pipeline=True)
     return SecureKMeans(cfg).fit(a, b)
@@ -103,7 +103,7 @@ def run(quick: bool = False):
     # one shared fitted model for every drain rep, plus one untimed
     # warmup drain so lazy predict-plan caches fill before timing
     km = SecureKMeans(KMeansConfig(k=k, iters=iters, seed=3,
-                                   backend="pallas", sparse=True,
+                                   backend="auto", sparse=True,
                                    batch_size=bs, offline="pooled",
                                    pipeline=True))
     res_serve = km.fit(a, b)

@@ -41,6 +41,7 @@ import numpy as np
 
 from benchmarks.common import make_blobs
 from repro.core import protocol as P
+from repro.core.backend import get_backend
 from repro.core.he import OU_COST_S, Paillier, SimulatedPHE
 from repro.core.kmeans import KMeansConfig, SecureKMeans
 from repro.core.sparse import (CSRMatrix, default_value_bits, he2ss_layout,
@@ -93,7 +94,7 @@ def _he_model_row(x_csr, k):
 def _combo_row(partition, sparse, n, k, d, iters):
     x = make_blobs(n, d, k, seed=4, sparse_frac=0.8 if sparse else 0.0)
     a, b = _split(x, partition)
-    base = dict(k=k, iters=iters, seed=3, backend="pallas",
+    base = dict(k=k, iters=iters, seed=3, backend="auto",
                 partition=partition, sparse=sparse)
 
     # cold: first-of-its-shape fit pays trace + bulk gen + AOT compile
@@ -121,7 +122,8 @@ def _combo_row(partition, sparse, n, k, d, iters):
 
     row = {
         "partition": partition, "sparse": sparse,
-        "n": n, "k": k, "d": d, "iters": iters, "backend": "pallas",
+        "n": n, "k": k, "d": d, "iters": iters,
+        "backend": get_backend("auto").name,
         "offline_cold_s": round(cold.offline_dealer_seconds, 4),
         "offline_warm_s": round(warm.offline_dealer_seconds, 4),
         "plan_fit_s": round(plan_s, 4),
@@ -168,7 +170,7 @@ def _worker_scaling_row(n, k, d):
     x = make_blobs(n, d, k, seed=4, sparse_frac=0.8)
     a, b = _split(x, "vertical")
     km = SecureKMeans(KMeansConfig(k=k, iters=3, seed=3, sparse=True,
-                                   backend="pallas", offline="pooled"))
+                                   backend="auto", offline="pooled"))
     key, plan, _ = km.plan_fit(a.shape, b.shape)
     TripleBank(seed=3).provision(key, plan)   # warmup: dispatch caches etc.
     walls = {}

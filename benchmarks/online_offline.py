@@ -27,9 +27,9 @@ come from computing something different.
 
 Writes benchmarks/BENCH_online.json: one row per combo, plus a larger
 n=4096 reference row in full mode. Reference config (full mode): n=1024,
-k=8, d=32, 3 iterations, pallas backend; --quick drops to n=256 for the
-per-PR smoke run (wired as `python -m benchmarks.run --only online_offline
---quick`).
+k=8, d=32, 3 iterations, the "auto" (xla) backend; --quick drops to n=256
+for the per-PR smoke run (wired as `python -m benchmarks.run --only
+online_offline --quick`).
 """
 from __future__ import annotations
 
@@ -39,6 +39,7 @@ import os
 import numpy as np
 
 from benchmarks.common import make_blobs
+from repro.core.backend import get_backend
 from repro.core.kmeans import KMeansConfig, SecureKMeans
 
 BENCH_PATH = os.path.join(os.path.dirname(__file__), "BENCH_online.json")
@@ -64,7 +65,7 @@ def _assert_bit_exact(r0, r1):
 def _combo_row(partition, sparse, n, k, d, iters):
     x = make_blobs(n, d, k, seed=4, sparse_frac=0.8 if sparse else 0.0)
     a, b = _split(x, partition)
-    base = dict(k=k, iters=iters, seed=3, backend="pallas",
+    base = dict(k=k, iters=iters, seed=3, backend="auto",
                 partition=partition, sparse=sparse)
 
     # cold pooled fit: pays the dry-run trace + S1/S3 AOT compile and warms
@@ -79,7 +80,8 @@ def _combo_row(partition, sparse, n, k, d, iters):
 
     return {
         "partition": partition, "sparse": sparse,
-        "n": n, "k": k, "d": d, "iters": iters, "backend": "pallas",
+        "n": n, "k": k, "d": d, "iters": iters,
+        "backend": get_backend("auto").name,
         "launches_per_iter": 2,            # S1 + S3 (Protocol 2 is a host
         # callback between them on the sparse combos)
         "ondemand_loop_s": round(res_od.loop_seconds, 4),

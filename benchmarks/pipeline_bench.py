@@ -51,7 +51,7 @@ def _assert_bit_exact(r0, r1):
 def _fit_row(n, d, k, iters, batch_size, offline, reps=3):
     x = make_blobs(n, d, k, seed=4, sparse_frac=0.8)
     a, b = x[:, :d // 2], x[:, d // 2:]
-    base = dict(k=k, iters=iters, seed=3, backend="pallas", sparse=True,
+    base = dict(k=k, iters=iters, seed=3, backend="auto", sparse=True,
                 batch_size=batch_size)
     # warmup: compile the batch/finalize programs, trace the stage plans
     SecureKMeans(KMeansConfig(**base, offline=offline)).fit(a, b)
@@ -88,7 +88,7 @@ def _serve_row(n_train, d, k, rung, requests):
     x = make_blobs(n_train, d, k, seed=7, sparse_frac=0.8)
     a, b = x[:, :d // 2], x[:, d // 2:]
     km = SecureKMeans(KMeansConfig(k=k, iters=2, seed=3, sparse=True,
-                                   backend="pallas", offline="pooled"))
+                                   backend="auto", offline="pooled"))
     res = km.fit(a, b)
     stream = make_blobs(rung * requests, d, k, seed=9, sparse_frac=0.8)
     outs, secs = {}, {}

@@ -13,16 +13,21 @@ funnelled through one small dispatch interface (DESIGN.md §7):
 
 Three implementations:
 
-  * ``xla``    — pure jnp (the seed behaviour; fallback and bit-exact oracle).
-  * ``pallas`` — the purpose-built kernels in ``repro.kernels`` (interpret
-                 mode on CPU, real lowering on TPU).
+  * ``xla``    — pure jnp; the device path on every platform. Its ring
+                 matmuls run the signed 8-bit limb cascade of `ring_matmul`
+                 (DESIGN.md §3): no uint64 dot reaches the compiler, so the
+                 same code compiles for the TPU and runs in the CPU tests.
+  * ``pallas`` — the kernels in ``repro.kernels``, in interpret mode only.
+                 Their ring matmul and spmm still compute on uint64 inside
+                 the kernel, which Mosaic refuses, so constructing this
+                 backend with a TPU attached raises.
   * ``numpy``  — host-side, for the offline dealer in ``core/triples.py``
                  and Protocol 2's host-resident sparse data.
 
-Selection: ``get_backend("auto")`` picks ``pallas`` when a TPU is attached
-and ``xla`` otherwise; ``KMeansConfig.backend`` / ``Ctx.backend`` carry the
-choice through the protocol stack, so the pjit'd production path in
-``launch/kmeans_step`` and the simulated path in ``core/kmeans`` execute the
+Selection: ``get_backend("auto")`` resolves to ``xla`` on every platform, a
+TPU included. ``KMeansConfig.backend`` / ``Ctx.backend`` carry the choice
+through the protocol stack, so the compiled programs in
+``launch/kmeans_step`` and the eager path in ``core/kmeans`` execute the
 same dispatch. All implementations are bit-exact in Z_{2^64}: the parity
 tests assert equality, not closeness.
 """
@@ -77,6 +82,71 @@ def _csr_spmm_chunked(csr, y):
     return z
 
 
+# ---------------------------------------------------------------------------
+# the exact 64-bit ring matmul on 8-bit dots (DESIGN.md §3)
+# ---------------------------------------------------------------------------
+
+LIMBS = 8
+# Adding 0x80 to every byte before reading the bytes off gives signed
+# digits: if w' = w + BIAS has bytes b_i, then w = sum (b_i - 128) 256^i
+# mod 2^64, every digit in [-128, 127].
+_DIGIT_BIAS = np.uint64(0x8080808080808080)
+# A digit product is at most 128 * 128 = 2^14 in magnitude, so a dot of
+# K digit pairs is exact in int32 while K * 2^14 <= 2^31 - 1.
+MAX_CONTRACTION = (2 ** 31 - 1) >> 14       # 131071
+
+
+def _digits(x):
+    """(8, *x.shape) int8 signed base-256 digits of uint64 words."""
+    w = jnp.asarray(x, ring.DTYPE) + jnp.uint64(_DIGIT_BIAS)
+    shift = np.arange(0, 64, 8, dtype=np.uint64).reshape((LIMBS,)
+                                                          + (1,) * w.ndim)
+    return (((w[None] >> shift) & 0xFF).astype(jnp.int32) - 128) \
+        .astype(jnp.int8)
+
+
+# digit product (i, j) lands at bit 8(i+j); those with i+j >= 8 vanish
+_PAIR_SHIFT = 8 * (np.arange(LIMBS)[:, None, None, None]
+                   + np.arange(LIMBS)[None, None, :, None])
+_PAIR_KEEP = _PAIR_SHIFT < 64
+
+
+def _cascade(a, b):
+    """a (*B, m, K) @ b (*B, K, n) mod 2^64 for K <= MAX_CONTRACTION.
+
+    a*b = sum_{i+j<=7} (A_i @ B_j) 2^(8(i+j)) mod 2^64 over the digit
+    matrices A_i, B_j: 36 int8 x int8 -> int32 partial products,
+    recombined by uint64 shift-adds. One dot forms all 64 digit products
+    (the 28 with i+j >= 8 are masked off): one op in place of 36 keeps the
+    compiled programs small, at 64/36 of the minimal MXU work."""
+    nb = a.ndim - 2
+    batch = tuple(range(1, nb + 1))
+    p = jax.lax.dot_general(
+        _digits(a), _digits(b), (((nb + 2,), (nb + 1,)), (batch, batch)),
+        preferred_element_type=jnp.int32)           # (*B, 8, m, 8, n)
+    p = p.astype(jnp.int64).astype(ring.DTYPE) \
+        << jnp.asarray(np.where(_PAIR_KEEP, _PAIR_SHIFT, 0), ring.DTYPE)
+    p = jnp.where(_PAIR_KEEP, p, jnp.uint64(0))
+    return p.sum((nb, nb + 2), dtype=ring.DTYPE)
+
+
+@jax.jit
+def ring_matmul(a, b):
+    """Exact a (*B, m, K) @ b (*B, K, n) mod 2^64 on 8-bit dots.
+
+    A contraction longer than MAX_CONTRACTION is split into pieces that
+    stay exact; their products add in uint64."""
+    a = jnp.asarray(a, ring.DTYPE)
+    b = jnp.asarray(b, ring.DTYPE)
+    kdim = a.shape[-1]
+    out = None
+    for lo in range(0, max(kdim, 1), MAX_CONTRACTION):
+        hi = min(kdim, lo + MAX_CONTRACTION)
+        part = _cascade(a[..., lo:hi], b[..., lo:hi, :])
+        out = part if out is None else out + part
+    return out
+
+
 class RingBackend:
     """Dispatch interface for the local ring ops on the online hot path."""
 
@@ -115,13 +185,12 @@ class RingBackend:
 
 
 class XlaBackend(RingBackend):
-    """Pure-jnp implementation — the seed behaviour and the parity oracle."""
+    """Pure-jnp implementation — the device path on every platform."""
 
     name = "xla"
 
     def ring_mm(self, a, b):
-        return jnp.matmul(jnp.asarray(a, ring.DTYPE),
-                          jnp.asarray(b, ring.DTYPE))
+        return ring_matmul(a, b)
 
     def ring_spmm(self, blocks, idx, counts, y):
         blocks = jnp.asarray(blocks, ring.DTYPE)
@@ -129,7 +198,7 @@ class XlaBackend(RingBackend):
         nrb, maxb, bm, bk = blocks.shape
         k = y.shape[1]
         y_tiles = y.reshape(-1, bk, k)[jnp.asarray(idx)]    # (nrb, maxb, bk, k)
-        contrib = jnp.matmul(blocks, y_tiles)               # (nrb, maxb, bm, k)
+        contrib = ring_matmul(blocks, y_tiles)              # (nrb, maxb, bm, k)
         keep = (jnp.arange(maxb)[None, :] < jnp.asarray(counts)[:, None])
         contrib = jnp.where(keep[..., None, None], contrib, jnp.uint64(0))
         return contrib.sum(1).reshape(nrb * bm, k)
@@ -149,60 +218,57 @@ class XlaBackend(RingBackend):
 
 
 class PallasBackend(RingBackend):
-    """Routes through the Pallas kernels (interpret on CPU, lowered on TPU)."""
+    """Routes through the Pallas kernels, in interpret mode. The ring
+    matmul and spmm kernels compute on uint64 inside the kernel, which
+    Mosaic cannot lower, so this backend refuses a TPU outright instead of
+    emulating the kernels there."""
 
     name = "pallas"
 
-    def __init__(self, interpret: bool | None = None,
-                 bm: int = 128, bk: int = 128, bn: int = 128):
-        if interpret is None:
-            interpret = not _has_tpu()
-        self.interpret = interpret
-        self.bm, self.bk, self.bn = bm, bk, bn
+    def __init__(self):
+        if _has_tpu():
+            raise RuntimeError(
+                "the pallas ring backend runs in interpret mode only: its "
+                "ring_mm/ring_spmm kernels compute on uint64, which Mosaic "
+                "does not lower for a TPU. Use backend='xla' (what 'auto' "
+                "resolves to), whose limb cascade compiles for the chip.")
 
     def ring_mm(self, a, b):
         from repro.kernels import ops
         return ops.ring_matmul(jnp.asarray(a, ring.DTYPE),
-                               jnp.asarray(b, ring.DTYPE),
-                               bm=self.bm, bk=self.bk, bn=self.bn,
-                               interpret=self.interpret)
+                               jnp.asarray(b, ring.DTYPE), interpret=True)
 
     def ring_spmm(self, blocks, idx, counts, y):
         from repro.kernels import ops
         return ops.spmm(jnp.asarray(blocks, ring.DTYPE), jnp.asarray(idx),
                         jnp.asarray(counts), jnp.asarray(y, ring.DTYPE),
-                        interpret=self.interpret)
+                        interpret=True)
 
     def ks_fused(self, x, e0, f0, u0, v0, z0, el, fl, ul, vl, zl, *,
                  party0: bool):
-        from repro.kernels.ksadder import ks_carry_share
+        from repro.kernels.ksadder import from_lanes, ks_carry_share, to_lanes
         shape = jnp.shape(x)
         size = max(1, int(np.prod(shape, dtype=np.int64)))
         bn = 128
-        rows = -(-size // bn)
-        if self.interpret:
-            # single grid cell: the interpret emulation pays per-grid-step,
-            # so tiling rows 8 at a time made this op ~60x slower than XLA
-            bm = rows
-        else:
-            bm = 8
-            rows += (-rows) % bm
-        padded = rows * bn
+        rows = -(-2 * size // bn)       # two uint32 lanes per ring word
+        pad = rows * bn - 2 * size
 
         def flat2d(t):
-            t = jnp.asarray(t, ring.DTYPE).reshape(-1)
-            return jnp.pad(t, (0, padded - t.size)).reshape(rows, bn)
+            t = to_lanes(jnp.asarray(t, ring.DTYPE).reshape(-1))
+            return jnp.pad(t, (0, pad)).reshape(rows, bn)
 
         def lvl2d(t):
             t = jnp.asarray(t, ring.DTYPE).reshape(len(KS_LEVELS), 2, -1)
-            t = jnp.pad(t, ((0, 0), (0, 0), (0, padded - t.shape[-1])))
+            t = jnp.pad(to_lanes(t), ((0, 0), (0, 0), (0, pad)))
             return t.reshape(len(KS_LEVELS), 2, rows, bn)
 
+        # single grid cell: the interpret emulation pays per grid step, so
+        # tiling rows 8 at a time made this op ~60x slower than XLA
         out = ks_carry_share(flat2d(x), flat2d(e0), flat2d(f0), flat2d(u0),
                              flat2d(v0), flat2d(z0), lvl2d(el), lvl2d(fl),
                              lvl2d(ul), lvl2d(vl), lvl2d(zl), party0=party0,
-                             bm=bm, bn=bn, interpret=self.interpret)
-        return out.reshape(-1)[:size].reshape(shape)
+                             bm=rows, bn=bn, interpret=True)
+        return from_lanes(out.reshape(-1)[:2 * size]).reshape(shape)
 
 
 class NumpyBackend(RingBackend):
@@ -245,22 +311,20 @@ _INSTANCES: dict[str, RingBackend] = {}
 
 
 def _has_tpu() -> bool:
-    try:
-        return any(d.platform == "tpu" for d in jax.devices())
-    except Exception:
-        return False
+    """Whether JAX's default backend is a TPU. Device-initialisation errors
+    propagate: a chip that another process holds must fail loudly, not
+    turn into a CPU run."""
+    return jax.default_backend() == "tpu"
 
 
 def get_backend(name: "str | RingBackend | None" = "auto") -> RingBackend:
     """Resolve a backend name ('auto'|'xla'|'pallas'|'numpy') or pass an
-    instance through. 'auto' = pallas when a TPU is attached, xla otherwise
-    (interpret-mode pallas is always *available* but only wins on TPU)."""
+    instance through. 'auto' = xla on every platform: it is the one
+    backend whose every kernel compiles for the TPU."""
     if isinstance(name, RingBackend):
         return name
-    if name is None:
-        name = "auto"
-    if name == "auto":
-        name = "pallas" if _has_tpu() else "xla"
+    if name is None or name == "auto":
+        name = "xla"
     if name not in _INSTANCES:
         try:
             cls = {"xla": XlaBackend, "pallas": PallasBackend,

@@ -9,9 +9,13 @@ u32 ring matmul is decomposed into 16-bit limbs —
     a*b mod 2^32 = ll + ((lh + hl) << 16)        (hh*2^32 vanishes)
 
 where ll/lh/hl are int32 matmuls of 16-bit limb matrices: products fit and
-int32 accumulation wraparound IS the ring reduction. The u64 variant uses the
-same blocking with native uint64 lanes (valid in interpret mode / CPU; on a
-real TPU it extends to a 4-limb cascade — same structure, 10 partial matmuls).
+int32 accumulation wraparound IS the ring reduction. That trick is exact for
+the 32-bit ring only. Mod 2^64 the lowest limb product needs all 64 bits,
+which no int32 accumulator holds; the exact 64-bit matmul is the signed
+8-bit cascade of `core/backend.ring_matmul` (36 int8 partial products with
+int32 accumulation). The u64 kernel here is a native uint64 matmul, valid
+in interpret mode only: Mosaic refuses it ("matmul acc to be 32-bit"), and
+`core/backend.PallasBackend` refuses a TPU for that reason.
 
 Blocking: (bm x bk) @ (bk x bn) MXU-aligned tiles (multiples of 128 on the
 lane dim), f32-free, VMEM accumulator scratch carried over the k grid axis.
@@ -60,7 +64,7 @@ def _kernel_u64(a_ref, b_ref, o_ref, acc_ref, *, n_kblocks: int):
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # native uint64 lanes (interpret/CPU); TPU: 4x16-bit limb cascade
+    # native uint64 lanes: interpret mode only (see module docstring)
     acc_ref[...] += jnp.matmul(a_ref[...], b_ref[...])
 
     @pl.when(kb == n_kblocks - 1)

@@ -13,8 +13,11 @@ The dense operand Y (the small k x d centroid block in K-means; the paper's
 dynamically with the tile's column-block id — valid while d*k*4B fits the
 ~16 MB VMEM budget, which `ops.spmm` asserts.
 
-Supports f32 (plaintext path) and the u32 ring via the same 16-bit limb
-trick as kernels/modmatmul.
+Supports f32 (plaintext path), the u32 ring via the same 16-bit limb trick
+as kernels/modmatmul, and the u64 ring as a native uint64 tile matmul. The
+u64 variant runs in interpret mode only: Mosaic has no 64-bit integer
+type, so `core/backend.PallasBackend` refuses a TPU, and the chip runs the
+XLA backend's ELL spmm on the signed 8-bit limb cascade (DESIGN.md §3).
 """
 from __future__ import annotations
 
@@ -28,8 +31,7 @@ from jax.experimental import pallas as pl
 
 def _tile_matmul(xb, yb, dtype):
     if dtype == jnp.uint64:
-        # native uint64 lanes (interpret/CPU); on a real TPU this tile
-        # matmul extends to the 4-limb cascade of kernels/modmatmul
+        # native uint64 lanes: interpret mode only (see module docstring)
         return jnp.matmul(xb, yb)
     if dtype == jnp.uint32:
         mask16 = jnp.uint32(0xFFFF)
@@ -60,7 +62,7 @@ def _kernel(idx_ref, cnt_ref, blocks_ref, y_ref, o_ref, *, bk: int,
     def row_block(g):
         def body(j, acc):
             start = idx_ref[g, j].astype(jnp.int32) * jnp.int32(bk)
-            yb = pl.load(y_ref, (pl.ds(start, bk), slice(None)))
+            yb = y_ref[pl.ds(start, bk), :]
             xb = blocks_ref[g, j]
             contrib = _tile_matmul(xb, yb, dtype)
             keep = (j < cnt_ref[g]).astype(contrib.dtype)
@@ -71,7 +73,7 @@ def _kernel(idx_ref, cnt_ref, blocks_ref, y_ref, o_ref, *, bk: int,
         o_ref[0] = row_block(0)
     else:
         def row(g, carry):
-            pl.store(o_ref, (g, slice(None), slice(None)), row_block(g))
+            o_ref[g] = row_block(g)
             return carry
         jax.lax.fori_loop(0, group, row, 0)
 

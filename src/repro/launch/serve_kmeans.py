@@ -47,6 +47,7 @@ import numpy as np
 from repro.core.fraud import FraudDataset, detect_outliers, jaccard
 from repro.core.kmeans import KMeansConfig, SecureKMeans
 from repro.core.triples import TripleBank, serve_seed
+from repro.launch.compile_cache import use_compile_cache
 from repro.obs import metrics as _metrics
 from repro.obs import trace as _trace
 from repro.serve import ScoringService
@@ -447,6 +448,7 @@ def main() -> None:
                     help="log a one-line stats summary (latency quantiles "
                          "+ bank_stock) every this many seconds")
     args = ap.parse_args()
+    use_compile_cache()
     if args.supervised:
         import sys
         if args.serve_port is None:

@@ -36,6 +36,26 @@ import urllib.request
 import numpy as np
 
 
+def ensure_chip_free(what: str) -> None:
+    """Refuse to spawn a child that needs the chip from a process that
+    holds it. A process that has initialised a JAX backend on an
+    accelerator holds the chip until it exits, and the child would then
+    fail or hang: run such children before the parent touches JAX.
+    Checking initialises no backend."""
+    if "jax" not in sys.modules:
+        return
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
+        return
+    import jax
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            f"{what}: this process has initialised a JAX backend on the "
+            "accelerator and holds the chip, so a child that needs it "
+            "would fail or hang; spawn device children before the parent "
+            "touches JAX")
+
+
 def free_port(host: str = "127.0.0.1") -> int:
     """A currently-free TCP port. The supervisor picks ports up front so
     every incarnation of a child listens on the SAME address and the
@@ -80,7 +100,11 @@ class SupervisedChild:
     `argv_for` is either a plain argv list (same every incarnation) or a
     callable `incarnation -> argv` — the chaos bench uses the callable
     to arm kill-points on incarnation 0 only, so a restart doesn't
-    re-kill itself at the same seam forever."""
+    re-kill itself at the same seam forever.
+
+    `needs_device=False` pins the child to the CPU backend (a pure wire
+    peer); a child that needs the device may only be started by a process
+    that does not hold the chip (`ensure_chip_free`)."""
 
     def __init__(self, name: str, argv_for, *,
                  policy: RestartPolicy | None = None,
@@ -89,12 +113,17 @@ class SupervisedChild:
                  log_path: str | None = None,
                  ready_pattern: str | None = None,
                  health_url: str | None = None,
-                 on_line=None):
+                 on_line=None, needs_device: bool = True):
         self.name = name
+        self.needs_device = needs_device
         self._argv_for = argv_for if callable(argv_for) \
             else (lambda _i: list(argv_for))
         self.policy = policy or RestartPolicy()
         self.terminal_codes = set(terminal_codes)
+        if not needs_device:
+            # pinned to the CPU backend: it never contends for the chip
+            env = dict(os.environ if env is None else env,
+                       JAX_PLATFORMS="cpu")
         self.env = env
         self.cwd = cwd
         self.log_path = log_path
@@ -118,6 +147,8 @@ class SupervisedChild:
 
     # -- lifecycle -------------------------------------------------------
     def start(self) -> "SupervisedChild":
+        if self.needs_device:
+            ensure_chip_free(f"supervised child {self.name!r}")
         self._thread.start()
         return self
 

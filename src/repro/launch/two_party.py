@@ -50,6 +50,7 @@ from repro.core.channel import (FaultyTransport, PeerProgress,
                                 SocketTransport, WireSession, WireTimeout,
                                 serve_peer, session_key)
 from repro.core.kmeans import KMeansConfig, SecureKMeans
+from repro.launch.compile_cache import use_compile_cache
 from repro.obs import trace as _trace
 
 
@@ -345,6 +346,7 @@ def main(argv=None) -> None:
                          "Chrome-trace JSON here on exit (merge A+B "
                          "files with repro.obs.merge_traces)")
     args = ap.parse_args(argv)
+    use_compile_cache()
     try:
         if args.role == "B":
             if args.port == 0:

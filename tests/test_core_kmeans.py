@@ -1,7 +1,7 @@
 """System tests: secure K-means vs plaintext oracle; Protocol 2; HE; fraud."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import protocol as P
 from repro.core import ring

@@ -2,7 +2,7 @@
 comparison, argmin, reciprocal, truncation)."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import protocol as P
 from repro.core import ring

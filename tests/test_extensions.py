@@ -78,7 +78,8 @@ def test_ks_carry_kernel_matches_protocol():
     from repro.core.channel import CommLog
     from repro.core.sharing import BShare, rec_b, share
     from repro.core.triples import TrustedDealer
-    from repro.kernels.ksadder import ks_carry_share, LEVELS
+    from repro.kernels.ksadder import (LEVELS, from_lanes, ks_carry_share,
+                                       to_lanes)
 
     rng = np.random.default_rng(3)
     n, m = 16, 128
@@ -120,12 +121,13 @@ def test_ks_carry_kernel_matches_protocol():
                         for lv in state[1:]])
         zl = jnp.stack([(lv[2].z.b0 if party0 else lv[2].z.b1)
                         for lv in state[1:]])
-        carries[party0] = ks_carry_share(
-            sh.s0 if party0 else sh.s1, e0, f0,
-            t0.u.b0 if party0 else t0.u.b1,
-            t0.v.b0 if party0 else t0.v.b1,
-            t0.z.b0 if party0 else t0.z.b1,
-            el, fl, ul, vl, zl, party0=party0)
+        # the kernel runs on uint32 lanes: each ring word's two halves
+        args = (sh.s0 if party0 else sh.s1, e0, f0,
+                t0.u.b0 if party0 else t0.u.b1,
+                t0.v.b0 if party0 else t0.v.b1,
+                t0.z.b0 if party0 else t0.z.b1, el, fl, ul, vl, zl)
+        carries[party0] = from_lanes(ks_carry_share(
+            *(to_lanes(a) for a in args), party0=party0))
     # (a) fused kernel == sequential oracle, per party share
     np.testing.assert_array_equal(np.asarray(carries[True], np.uint64),
                                   np.asarray(g.b0, np.uint64))

@@ -184,3 +184,25 @@ def test_online_iteration_backend_parity():
         outs[backend] = (np.asarray(s0, np.uint64), np.asarray(s1, np.uint64))
     np.testing.assert_array_equal(outs["xla"][0], outs["pallas"][0])
     np.testing.assert_array_equal(outs["xla"][1], outs["pallas"][1])
+
+
+@pytest.mark.parametrize("env_dir", [None, "/var/cache/jax-elsewhere"])
+def test_entry_point_compile_cache_location(monkeypatch, env_dir):
+    """$JAX_COMPILATION_CACHE_DIR wins and is left to JAX; otherwise the
+    entry points use one fixed directory at the root of the checkout."""
+    import os
+    from repro.launch import compile_cache
+    calls = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda name, value: calls.append((name, value)))
+    if env_dir is None:
+        monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    else:
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", env_dir)
+    got = compile_cache.use_compile_cache()
+    if env_dir is None:
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        assert got == os.path.join(root, ".jax_cache")
+        assert calls == [("jax_compilation_cache_dir", got)]
+    else:
+        assert got == env_dir and calls == []

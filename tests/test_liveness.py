@@ -18,7 +18,7 @@ import time
 
 import pytest
 
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 from repro.core.channel import (RESP_BIT, ReliableChannel, Responder,
                                 T_EXCHANGE, WireError, WireTimeout,
                                 decode_frame, encode_frame)

@@ -11,7 +11,10 @@ Load-bearing properties:
 * `argv_for(incarnation)` lets the caller arm crash switches on
   incarnation 0 only;
 * backoff jitter is seeded — two identically-configured supervisors
-  pause identically (deterministic chaos runs).
+  pause identically (deterministic chaos runs);
+* one process per chip: a process holding the accelerator refuses to
+  start a child that needs it, and a child that needs no device runs
+  pinned to the CPU backend.
 """
 import sys
 
@@ -19,7 +22,8 @@ import pytest
 
 from repro.launch.supervisor import (ChildEvent, RestartPolicy,
                                      SupervisedChild, Supervisor, child_env,
-                                     free_port, python_argv)
+                                     ensure_chip_free, free_port,
+                                     python_argv)
 
 FAST = RestartPolicy(max_restarts=5, backoff_s=0.01, backoff_max_s=0.02,
                      crash_loop_window_s=0.0, crash_loop_threshold=3)
@@ -170,3 +174,24 @@ def test_restart_latencies_without_readiness_use_spawn():
     c = SupervisedChild("x", ["true"])
     c.events = evs
     assert c.restart_latencies() == [0.5]
+
+
+def test_chip_holder_refuses_to_start_a_device_child(tmp_path,
+                                                     monkeypatch):
+    import jax
+    import jax.numpy as jnp
+    jnp.zeros(1).block_until_ready()         # this process's backend is up
+    ensure_chip_free("cpu parent")           # a CPU backend holds no chip
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="holds the chip"):
+        ensure_chip_free("tpu parent")
+    c = _script_child(tmp_path, "print('hi')", policy=FAST)
+    with pytest.raises(RuntimeError, match="holds the chip"):
+        c.start()
+    # a child that needs no device may still start, pinned off it
+    peer = _script_child(
+        tmp_path, "print('PLATFORMS', os.environ['JAX_PLATFORMS'])",
+        policy=FAST, needs_device=False)
+    peer.start()
+    assert peer.wait(timeout=30.0) and peer.success
+    assert any("PLATFORMS cpu" in line for line in peer.lines)

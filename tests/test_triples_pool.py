@@ -8,7 +8,7 @@ pjit flat-tensor level, and through a full SecureKMeans.fit for all four
 partition x sparsity combinations."""
 import numpy as np
 import pytest
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.kmeans import KMeansConfig, SecureKMeans
 from repro.core.triples import (PlanningDealer, PlanRequest, PooledDealer,

@@ -27,7 +27,7 @@ import time
 import numpy as np
 import pytest
 
-from _hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 from repro.core.channel import (CommLog, FaultyTransport, FrameCorrupt,
                                 FrameDecoder, FrameError, LoopbackTransport,
                                 NetModel, ReliableChannel, Responder,
