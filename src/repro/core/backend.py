@@ -204,12 +204,12 @@ class XlaBackend(RingBackend):
         return contrib.sum(1).reshape(nrb * bm, k)
 
     def ring_spmm_csr(self, csr, y, *, bm: int = 8, bk: int = 128):
-        # Protocol 2's sparse data is host-resident and the result returns
-        # to the host immediately; with no accelerator to feed, the chunked
-        # CSR loop beats an ELL densification (which blows up O(nrb*maxb)
-        # on skewed matrices) — the ELL ring_spmm above stays as the
-        # parity oracle for the pallas kernel.
-        return jnp.asarray(_csr_spmm_chunked(csr, y))
+        # Protocol 2's sparse data is host-resident and the result is
+        # masked on the host, so it stays there; with no accelerator to
+        # feed, the chunked CSR loop beats an ELL densification (which
+        # blows up O(nrb*maxb) on skewed matrices) — the ELL ring_spmm
+        # above stays as the parity oracle for the pallas kernel.
+        return _csr_spmm_chunked(csr, y)
 
     def ks_fused(self, x, e0, f0, u0, v0, z0, el, fl, ul, vl, zl, *,
                  party0: bool):

@@ -25,6 +25,7 @@ NetModel turns into Fig. 3's curves.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Literal
@@ -213,15 +214,20 @@ class SecureKMeans:
         with _trace.span("fit", rows=int(np.asarray(x_a).shape[0]),
                          k=self.cfg.k, iters=self.cfg.iters,
                          sparse=self.cfg.sparse,
-                         wired=wire is not None):
+                         wired=wire is not None), \
+                contextlib.ExitStack() as prologue:
+            # fit.prologue runs from here to the loop; each loop closes it
+            # as it starts
+            prologue.enter_context(_trace.span("fit.prologue"))
             return self._fit(x_a, x_b, dealer=dealer, wire=wire,
                              checkpoint=checkpoint, resume=resume,
-                             resume_step=resume_step)
+                             resume_step=resume_step, prologue=prologue)
 
     def _fit(self, x_a: np.ndarray, x_b: np.ndarray, *,
              dealer=None, wire=None, checkpoint=None,
              resume: bool = False,
-             resume_step: int | None = None) -> KMeansResult:
+             resume_step: int | None = None,
+             prologue: contextlib.ExitStack) -> KMeansResult:
         """Jointly cluster the two parties' data. `dealer` (optional)
         supplies the fit's correlated randomness from an EXTERNAL provider —
         pass a `TripleBank.dealer(key)` view over a bank provisioned with
@@ -246,7 +252,9 @@ class SecureKMeans:
         (the resume negotiation's agreed `min(step)`, DESIGN.md §16)
         instead restores the largest PUBLISHED step ≤ that value — a
         party holding a newer step than its peer witnessed rewinds to the
-        common one; no such step means a fresh start (also bit-exact)."""
+        common one; no such step means a fresh start (also bit-exact).
+
+        `prologue` holds the open `fit.prologue` span; the loop closes it."""
         cfg = self.cfg
         rng = np.random.default_rng(cfg.seed)
         ctx = P.make_ctx(cfg.seed, backend=cfg.backend, wire=wire)
@@ -259,10 +267,11 @@ class SecureKMeans:
         else:
             assert x_a.shape[1] == x_b.shape[1]
             n, d = x_a.shape[0] + x_b.shape[0], x_a.shape[1]
-        enc_a = _encode_np(x_a, cfg.f)
-        enc_b = _encode_np(x_b, cfg.f)
-        csr_a = CSRMatrix.from_dense(enc_a) if cfg.sparse else None
-        csr_b = CSRMatrix.from_dense(enc_b) if cfg.sparse else None
+        with _trace.span("fit.encode"):
+            enc_a = _encode_np(x_a, cfg.f)
+            enc_b = _encode_np(x_b, cfg.f)
+            csr_a = CSRMatrix.from_dense(enc_a) if cfg.sparse else None
+            csr_b = CSRMatrix.from_dense(enc_b) if cfg.sparse else None
 
         st = None
         if checkpoint is not None:
@@ -291,7 +300,10 @@ class SecureKMeans:
             mu = AShare(jnp.asarray(st.mu0), jnp.asarray(st.mu1))
             ctx.log.restore(st.comm)
         else:
-            mu = self._init_centroids(ctx, rng, x_a, x_b)
+            # the k initial rows' two shares go up to the device
+            with _trace.span("fit.init_centroids",
+                             h2d_bytes=2 * ring.nbytes((cfg.k, d))):
+                mu = self._init_centroids(ctx, rng, x_a, x_b)
 
         if cfg.batch_size is not None:
             # minibatch Lloyd: batched S1/S3-partial launches with secret-
@@ -299,7 +311,8 @@ class SecureKMeans:
             # host exchanges — its own loop below
             return self._fit_minibatch(ctx, enc_a, enc_b, csr_a, csr_b,
                                        mu, n, d, ext_dealer=dealer,
-                                       checkpoint=checkpoint, st=st)
+                                       checkpoint=checkpoint, st=st,
+                                       prologue=prologue)
         if st is not None and st.batch:
             raise ValueError(
                 "a mid-iteration (batch > 0) checkpoint can only resume a "
@@ -388,6 +401,7 @@ class SecureKMeans:
             for attr in ("n_matmul", "n_mul", "n_bin"):
                 setattr(ctx.dealer, attr, st.counters[attr])
 
+        prologue.close()
         t_start = time.perf_counter()
         dealer_s_pre = ctx.dealer.dealer_seconds
         h_iter = _h_iter_seconds()
@@ -496,7 +510,8 @@ class SecureKMeans:
     # Minibatch Lloyd — batched S1/S3-partial launches, pipelined exchanges
     # ------------------------------------------------------------------ #
     def _fit_minibatch(self, ctx, enc_a, enc_b, csr_a, csr_b, mu: AShare,
-                       n: int, d: int, ext_dealer=None, checkpoint=None,
+                       n: int, d: int, *, prologue: contextlib.ExitStack,
+                       ext_dealer=None, checkpoint=None,
                        st=None) -> KMeansResult:
         """Each iteration is one full pass over the data in
         ceil(n / batch_size)-row batches: per batch an S1 launch (distances
@@ -528,28 +543,35 @@ class SecureKMeans:
         from repro.launch.pipeline import run_pipeline
 
         t0 = time.perf_counter()
-        bounds, stage_plans, (fin_plan, fin_comm), _ = \
-            self._minibatch_slot_plans(enc_a.shape, enc_b.shape)
-        batches = []
-        for ((alo, ahi), (blo, bhi)), plans in zip(bounds, stage_plans):
-            ea, eb = enc_a[alo:ahi], enc_b[blo:bhi]
-            ca = CSRMatrix.from_dense(ea) if cfg.sparse else None
-            cb = CSRMatrix.from_dense(eb) if cfg.sparse else None
-            s1_plan, s1_comm, s3_plan, s3_comm = plans
-            batches.append({
-                "enc_a": ea, "enc_b": eb,
-                "dev_a": jnp.asarray(ea), "dev_b": jnp.asarray(eb),
-                "csr_a": ca, "csr_b": cb,
-                "csr_at": ca.transpose() if cfg.sparse else None,
-                "csr_bt": cb.transpose() if cfg.sparse else None,
-                "progs": K.fit_batch_programs(cfg.partition, cfg.sparse,
-                                              ea.shape, eb.shape, cfg.k,
-                                              backend=cfg.backend),
-                "s1_plan": s1_plan, "s1_comm": s1_comm,
-                "s3_plan": s3_plan, "s3_comm": s3_comm,
-                "a_rows": ahi - alo,
-            })
-        fin_prog = K.finalize_program(cfg.k, d, n, backend=cfg.backend)
+        with _trace.span("fit.plan"):
+            bounds, stage_plans, (fin_plan, fin_comm), _ = \
+                self._minibatch_slot_plans(enc_a.shape, enc_b.shape)
+            batches = []
+            for ((alo, ahi), (blo, bhi)), plans in zip(bounds, stage_plans):
+                ea, eb = enc_a[alo:ahi], enc_b[blo:bhi]
+                s1_plan, s1_comm, s3_plan, s3_comm = plans
+                batches.append({
+                    "enc_a": ea, "enc_b": eb,
+                    "progs": K.fit_batch_programs(cfg.partition, cfg.sparse,
+                                                  ea.shape, eb.shape, cfg.k,
+                                                  backend=cfg.backend),
+                    "s1_plan": s1_plan, "s1_comm": s1_comm,
+                    "s3_plan": s3_plan, "s3_comm": s3_comm,
+                    "a_rows": ahi - alo,
+                })
+            fin_prog = K.finalize_program(cfg.k, d, n, backend=cfg.backend)
+        if cfg.sparse:
+            with _trace.span("fit.encode"):
+                for b in batches:
+                    b["csr_a"] = CSRMatrix.from_dense(b["enc_a"])
+                    b["csr_b"] = CSRMatrix.from_dense(b["enc_b"])
+                    b["csr_at"] = b["csr_a"].transpose()
+                    b["csr_bt"] = b["csr_b"].transpose()
+        with _trace.span("fit.upload", h2d_bytes=sum(
+                b["enc_a"].nbytes + b["enc_b"].nbytes for b in batches)):
+            for b in batches:
+                b["dev_a"] = jnp.asarray(b["enc_a"])
+                b["dev_b"] = jnp.asarray(b["enc_b"])
         iter_slots = []
         for b in batches:
             iter_slots += [b["s1_plan"], b["s3_plan"]]
@@ -592,19 +614,22 @@ class SecureKMeans:
                     "minibatch fit(dealer=...) takes a TripleBank dealer "
                     "view (bank.dealer(key) over a plan_fit provisioning); "
                     f"got {type(ext_dealer).__name__}")
-            dealer = BankSlotDealer(bank, ext_dealer.key,
-                                    iter_slots * cfg.iters, log=dlog,
-                                    start_slot=start_slot)
+            with _trace.span("fit.dealer_start"):
+                dealer = BankSlotDealer(bank, ext_dealer.key,
+                                        iter_slots * cfg.iters, log=dlog,
+                                        start_slot=start_slot)
         else:
-            dealer = SlotDealer(iter_slots * cfg.iters, seed=cfg.seed,
-                                log=dlog,
-                                stream=(cfg.offline == "streamed"),
-                                start_slot=start_slot)
+            with _trace.span("fit.dealer_start"):
+                dealer = SlotDealer(iter_slots * cfg.iters, seed=cfg.seed,
+                                    log=dlog,
+                                    stream=(cfg.offline == "streamed"),
+                                    start_slot=start_slot)
         ctx.dealer = dealer
         if st is not None:
             for attr in ("n_matmul", "n_mul", "n_bin"):
                 setattr(dealer, attr, st.counters[attr])
         plan_s = time.perf_counter() - t0
+        prologue.close()
 
         t_start = time.perf_counter()
         h_iter = _h_iter_seconds()
@@ -649,10 +674,12 @@ class SecureKMeans:
                          for t, b in enumerate(batches) if t >= start_b]
                 run_pipeline(tasks, pipeline=cfg.pipeline)
                 _fp.probe("fit.finalize")
-                fin_view = dealer.acquire(base + 2 * len(batches))
-                flat_f = K.materialize_offline(fin_prog.requests, fin_view)
-                mu0, mu1 = fin_prog.fn(mu.s0, mu.s1, acc[0], acc[1],
-                                       acc[2], acc[3], *flat_f)
+                with _trace.span("fit.finalize", iter=it):
+                    fin_view = dealer.acquire(base + 2 * len(batches))
+                    flat_f = K.materialize_offline(fin_prog.requests,
+                                                   fin_view)
+                    mu0, mu1 = fin_prog.fn(mu.s0, mu.s1, acc[0], acc[1],
+                                           acc[2], acc[3], *flat_f)
                 mu = AShare(mu0, mu1)
                 ctx.log.merge(fin_comm, phase="online")
                 if cfg.tol is not None:
@@ -672,7 +699,8 @@ class SecureKMeans:
         finally:
             dealer.close()
 
-        c = _assemble_assignment(cfg.partition, c_parts, batches)
+        with _trace.span("fit.assemble"):
+            c = _assemble_assignment(cfg.partition, c_parts, batches)
         self.result_ = KMeansResult(
             centroids=mu, assignment=c, iters_run=it, log=ctx.log,
             dealer=dealer,

@@ -17,6 +17,7 @@ from __future__ import annotations
 
 import dataclasses
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -258,10 +259,12 @@ def msb_carry(ctx: Ctx, a: AShare) -> BShare:
     ul = [jnp.stack([t.u.b0 for t in lvl]), jnp.stack([t.u.b1 for t in lvl])]
     vl = [jnp.stack([t.v.b0 for t in lvl]), jnp.stack([t.v.b1 for t in lvl])]
     zl = [jnp.stack([t.z.b0 for t in lvl]), jnp.stack([t.z.b1 for t in lvl])]
-    g0 = ctx.backend.ks_fused(s0, e0, f0, t0.u.b0, t0.v.b0, t0.z.b0,
-                              el, fl, ul[0], vl[0], zl[0], party0=True)
-    g1 = ctx.backend.ks_fused(s1, e0, f0, t0.u.b1, t0.v.b1, t0.z.b1,
-                              el, fl, ul[1], vl[1], zl[1], party0=False)
+    # one name for the fused recombination in every program's op paths
+    with jax.named_scope("ks_fused"):
+        g0 = ctx.backend.ks_fused(s0, e0, f0, t0.u.b0, t0.v.b0, t0.z.b0,
+                                  el, fl, ul[0], vl[0], zl[0], party0=True)
+        g1 = ctx.backend.ks_fused(s1, e0, f0, t0.u.b1, t0.v.b1, t0.z.b1,
+                                  el, fl, ul[1], vl[1], zl[1], party0=False)
     # sum bit 63 = p_orig[63] ^ carry_in[63];  carry_in[63] = G[62]
     one = jnp.uint64(1)
     msb = bxor(BShare((s0 >> 63) & one, (s1 >> 63) & one),

@@ -176,9 +176,11 @@ def secure_sparse_matmul(ctx: Ctx, x: CSRMatrix, y_share_b: np.ndarray, he,
                          batched: bool = True) -> AShare:
     """Traced entry point for Protocol 2: the HE joint-product exchange is
     the dominant host-side hot seam of a sparse fit, so it gets its own
-    span (`he.exchange`, tagged with the problem shape)."""
-    with _trace.span("he.exchange", n=x.shape[0], d=x.shape[1],
-                     k=int(y_share_b.shape[1]), nnz=int(x.nnz)):
+    span (`he.exchange`, tagged with the problem shape and the bytes of
+    the two (n, k) result shares it puts on the device)."""
+    n, k = x.shape[0], int(y_share_b.shape[1])
+    with _trace.span("he.exchange", n=n, d=x.shape[1], k=k, nnz=int(x.nnz),
+                     h2d_bytes=2 * ring.nbytes((n, k))):
         return _secure_sparse_matmul(ctx, x, y_share_b, he,
                                      value_bits=value_bits, trunc_f=trunc_f,
                                      time_model=time_model, batched=batched)

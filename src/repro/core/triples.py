@@ -531,16 +531,27 @@ def _gen_tranche(rngs: dict, counts: dict):
     from persistent per-class RNG streams. Because a class's stream is
     advanced by exactly count*words_per_request words per call, consecutive
     tranches concatenate to the single stacked draw PooledDealer performs —
-    the bit-exactness property, chunked."""
+    the bit-exactness property, chunked.
+
+    Each class's three costs are spans of their own: `dealer.draw` (the
+    host draw and ring products), `dealer.upload` (the stacked arrays to
+    the device, with their `h2d_bytes`) and `dealer.split` (one device
+    slice per request and array)."""
     pools: dict[tuple, list] = {}
     nbytes = 0
     for key, count in counts.items():
         kind = key[0]
         shape = key[1:] if kind == "matmul" else key[1]
-        arrays = _gen_class(rngs[key], kind, shape, count)
-        stacked = tuple(jnp.asarray(a) for a in arrays)
-        pools[key] = [tuple(a[i] for a in stacked) for i in range(count)]
-        nbytes += sum(int(a.size) * 8 for a in stacked)
+        name = _key_to_str(key)
+        with _trace.span("dealer.draw", key=name, count=count):
+            arrays = _gen_class(rngs[key], kind, shape, count)
+        size = sum(a.nbytes for a in arrays)
+        with _trace.span("dealer.upload", key=name, count=count,
+                         h2d_bytes=size):
+            stacked = tuple(jnp.asarray(a) for a in arrays)
+        with _trace.span("dealer.split", key=name, count=count):
+            pools[key] = [tuple(a[i] for a in stacked) for i in range(count)]
+        nbytes += size
     return pools, nbytes
 
 
@@ -1070,6 +1081,10 @@ class SlotDealer:
         if not 0 <= i < len(self.slot_plans):
             raise IndexError(f"slot {i} out of range "
                              f"({len(self.slot_plans)} slots planned)")
+        with _trace.span("dealer.acquire", slot=i):
+            return self._acquire(i)
+
+    def _acquire(self, i: int) -> _SlotView:
         t0 = time.perf_counter()
         with self._cond:
             if i in self._acquired:
@@ -1772,6 +1787,10 @@ class BankSlotDealer:
         if not 0 <= i < len(self.slot_plans):
             raise IndexError(f"slot {i} out of range "
                              f"({len(self.slot_plans)} slots planned)")
+        with _trace.span("dealer.acquire", slot=i):
+            return self._acquire(i)
+
+    def _acquire(self, i: int) -> _SlotView:
         if i in self._acquired:
             raise PoolExhaustedError(
                 f"slot {i} was already acquired: each slot serves its "

@@ -671,7 +671,7 @@ def finalize_program(k: int, d: int, n: int,
     jax.eval_shape(trace)
     requests = list(rec.requests)
 
-    def fn(mu0, mu1, num0, num1, den0, den1, *flat):
+    def finalize_fn(mu0, mu1, num0, num1, den0, den1, *flat):
         ctx = P.Ctx(dealer=ListDealer(list(flat)), log=CommLog(),
                     backend=ring_backend)
         out = _s3_final_body(ctx, k, n, AShare(mu0, mu1),
@@ -681,7 +681,8 @@ def finalize_program(k: int, d: int, n: int,
     args = (_sds((k, d)), _sds((k, d)), _sds((k, d)), _sds((k, d)),
             _sds((k,)), _sds((k,))) \
         + tuple(offline_tensor_specs(requests, n))
-    prog = FinalizeProgram(jax.jit(fn).lower(*args).compile(), requests)
+    prog = FinalizeProgram(jax.jit(finalize_fn).lower(*args).compile(),
+                           requests)
     _FINALIZE_CACHE[key] = prog
     return prog
 
@@ -772,7 +773,7 @@ def predict_program(partition: str, sparse: bool, shape_a, shape_b, k: int,
     jax.eval_shape(trace)
     requests = list(rec1.requests)
 
-    def fn(xa, xb, mu0, mu1, *rest):
+    def predict_fn(xa, xb, mu0, mu1, *rest):
         he, flat = _split_he(rest, fgeo.he_shapes_s1())
         ctx = P.Ctx(dealer=ListDealer(flat), log=CommLog(),
                     backend=ring_backend)
@@ -787,7 +788,8 @@ def predict_program(partition: str, sparse: bool, shape_a, shape_b, k: int,
             _sds((k, d)), _sds((k, d))) \
         + tuple(_he_specs(fgeo.he_shapes_s1())) \
         + tuple(offline_tensor_specs(requests, n))
-    prog = PredictProgram(geo, jax.jit(fn).lower(*args).compile(), requests)
+    prog = PredictProgram(geo, jax.jit(predict_fn).lower(*args).compile(),
+                          requests)
     _PREDICT_PROGRAM_CACHE[key] = prog
     return prog
 

@@ -11,7 +11,11 @@ land on one absolute timeline), a monotonic duration, and its thread lane,
 and exports as Chrome-trace / Perfetto JSON (``chrome://tracing``,
 https://ui.perfetto.dev) — thread-lane aware, so the pipelined executor's
 pre(t+1)-under-launch(t) overlap is *visible* — plus an aggregated text
-flame summary for terminals.
+flame summary for terminals. An enabled span also enters a
+`jax.profiler.TraceAnnotation` of its name, so that while a profiler trace
+is being taken the span shows on that trace's host threads, on one clock
+with the device's operations (Perfetto, xprof); jax is imported on the
+first enabled span only, and a process without it records spans alone.
 
 Distributed request traces ride a **trace id**: an 8-byte token minted by
 the client (`new_trace_id`), carried inside wire frames (the
@@ -80,11 +84,26 @@ class _NoopSpan:
 _NOOP = _NoopSpan()
 
 
+_PROFILER = None      # jax.profiler once imported; False: no jax here
+
+
+def _annotation(name: str):
+    """A `jax.profiler.TraceAnnotation` of `name`, or None where jax cannot
+    be imported. Looked up on the module at each call, not cached."""
+    global _PROFILER
+    if _PROFILER is None:
+        try:
+            import jax.profiler as _PROFILER
+        except ImportError:
+            _PROFILER = False
+    return _PROFILER.TraceAnnotation(name) if _PROFILER else None
+
+
 class _Span:
     """One live span: records on `__exit__`. Cheap on purpose — two clock
-    reads plus one locked list append per span."""
+    reads, one profiler annotation and one locked list append per span."""
 
-    __slots__ = ("tracer", "name", "args", "t_epoch_us", "t0")
+    __slots__ = ("tracer", "name", "args", "t_epoch_us", "t0", "ann")
 
     def __init__(self, tracer: "Tracer", name: str, args: dict):
         self.tracer = tracer
@@ -92,6 +111,9 @@ class _Span:
         self.args = args
 
     def __enter__(self):
+        self.ann = _annotation(self.name)
+        if self.ann is not None:
+            self.ann.__enter__()
         self.t_epoch_us = time.time_ns() // 1_000
         self.t0 = time.perf_counter_ns()
         return self
@@ -99,6 +121,8 @@ class _Span:
     def __exit__(self, *exc):
         dur_us = max(0, (time.perf_counter_ns() - self.t0) // 1_000)
         self.tracer._record(self.name, self.t_epoch_us, dur_us, self.args)
+        if self.ann is not None:
+            self.ann.__exit__(*exc)
         return False
 
 

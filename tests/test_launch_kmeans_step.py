@@ -156,6 +156,32 @@ def test_fit_programs_two_launches_per_iteration(partition, sparse):
     assert bool(progs.geo.he_shapes_s3()) == sparse
 
 
+def test_compiled_programs_carry_stable_names():
+    """The device trace tells the programs apart by their module names and
+    finds kernels by their name paths: the finalize and predict programs
+    have names of their own, every Kogge-Stone recombination sits under
+    `ks_fused`, and every ring product keeps `ring_matmul` in its path."""
+    import re
+
+    import repro.launch.kmeans_step as K
+
+    fin = K.finalize_program(2, 8, 64, backend="xla").fn.as_text()
+    pred = K.predict_program("vertical", False, (16, 4), (16, 4), 2,
+                             backend="xla").fn.as_text()
+    s1 = K.fit_batch_programs("vertical", False, (16, 4), (16, 4), 2,
+                              backend="xla").s1.as_text()
+    assert fin.startswith("HloModule jit_finalize_fn")
+    assert pred.startswith("HloModule jit_predict_fn")
+    for text, fn in ((fin, "finalize_fn"), (pred, "predict_fn"),
+                     (s1, "s1_fn")):
+        paths = set(re.findall(r'op_name="([^"]*)"', text))
+        assert any(p.startswith(f"jit({fn})/ks_fused/") for p in paths)
+    for text in (pred, s1):
+        dots = re.findall(r'= s32\[[^\]]*\]\S* (?:dot|convolution)\('
+                          r'.*op_name="([^"]*)"', text)
+        assert dots and all("ring_matmul" in p for p in dots)
+
+
 def test_fit_geometry_validation():
     from repro.launch.kmeans_step import FitGeometry
     with pytest.raises(ValueError, match="unknown partition"):

@@ -11,9 +11,15 @@ snapshot equality is exact, not approximate; (5) a rid-pinned retry wave
 across a seeded faulty wire yields EXACTLY ONE server-side request span,
 and the client + server span files merge into one consistent timeline
 joined by the trace id; (6) FrameDecoder error paths tally into the
-registry.
+registry; (7) an enabled span is also a profiler annotation, on the
+device trace's clock, and a disabled one makes none; (8) a minibatch fit
+spans its prologue, dealer waits and the dealer's draw, upload and split,
+and counts each host->device byte once, where it moves.
 """
+import glob
 import json
+import os
+import sys
 import threading
 import time
 import urllib.request
@@ -585,3 +591,154 @@ def test_health_route_404_without_callback_and_cb_error_is_503():
         assert code == 503 and "DEGRADED" in body
     finally:
         srv.stop()
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock, and the minibatch fit's stage spans
+# ---------------------------------------------------------------------------
+
+def test_disabled_tracer_creates_no_trace_annotation(monkeypatch):
+    import jax.profiler
+    made = []
+    real = jax.profiler.TraceAnnotation
+
+    def counting(name, **kw):
+        made.append(name)
+        return real(name, **kw)
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", counting)
+    assert not _trace.get_tracer().enabled
+    off = _trace.Tracer(enabled=False)
+    with off.span("off.a", i=1):
+        pass
+    with _trace.span("off.b"):
+        pass
+    assert made == []
+    on = _trace.Tracer(enabled=True)
+    with on.span("on.c", i=2):
+        pass
+    assert made == ["on.c"]
+    assert [e["name"] for e in on.events()] == ["on.c"]
+
+
+def test_enabled_span_shows_on_a_profiler_host_line(tmp_path):
+    import jax
+    from jax.profiler import ProfileData
+    t = _trace.Tracer(enabled=True)
+    opts = jax.profiler.ProfileOptions()
+    opts.host_tracer_level = 1
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        with t.span("obs.on_the_clock", i=1):
+            with t.span("obs.inner"):
+                time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    found = {}
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("obs."):
+                    found[ev.name] = (ev.start_ns, ev.duration_ns)
+    assert set(found) == {"obs.on_the_clock", "obs.inner"}
+    outer, inner = found["obs.on_the_clock"], found["obs.inner"]
+    assert inner[1] >= 2_000_000
+    assert outer[0] <= inner[0]
+    assert inner[0] + inner[1] <= outer[0] + outer[1]
+    # the recorded event keeps its epoch-us format and its args
+    (ev,) = [e for e in t.events() if e["name"] == "obs.on_the_clock"]
+    assert ev["ph"] == "X" and ev["args"] == {"i": 1} and ev["dur"] >= 2_000
+
+
+def _bench_work():
+    """bench/work.py, the benchmark's count of a launch's triple bytes."""
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench import work
+    return work
+
+
+PROLOGUE_CHILDREN = {"fit.encode", "fit.init_centroids", "fit.plan",
+                     "fit.upload", "fit.dealer_start"}
+
+
+@pytest.fixture(scope="module", params=["dense", "sparse"])
+def traced_minibatch_fit(request):
+    """One traced minibatch fit at the benchmark's rehearsal size: n=2,000
+    in batches of 1,024 and 976, d=64 split 32/32, k=2, the streamed slot
+    dealer and the pipelined executor."""
+    sparse = request.param == "sparse"
+    rng = np.random.default_rng(11)
+    x = rng.random((2000, 64))
+    if sparse:
+        x = x * (rng.random(x.shape) < 0.12)
+    cfg = KMeansConfig(k=2, iters=1, seed=4, partition="vertical",
+                       sparse=sparse, offline="streamed", batch_size=1024,
+                       pipeline=True)
+    t = _trace.get_tracer()
+    was = t.enabled
+    t.reset()
+    _trace.configure(enabled=True)
+    try:
+        res = SecureKMeans(cfg).fit(x[:, :32], x[:, 32:])
+        res.centroids.s0.block_until_ready()
+        events = t.events()
+    finally:
+        _trace.configure(enabled=was)
+        t.reset()
+    return sparse, x, res, events
+
+
+def test_minibatch_fit_spans_its_stages(traced_minibatch_fit):
+    sparse, _x, _res, events = traced_minibatch_fit
+    names = {e["name"] for e in events}
+    assert PROLOGUE_CHILDREN | {
+        "fit", "fit.prologue", "fit.finalize", "fit.assemble",
+        "dealer.acquire", "dealer.draw", "dealer.upload",
+        "dealer.split"} <= names
+    assert ("he.exchange" in names) == sparse
+    # the prologue holds its five children and ends before the first batch
+    (pro,) = [e for e in events if e["name"] == "fit.prologue"]
+    lo, hi = pro["ts"], pro["ts"] + pro["dur"]
+    for e in events:
+        if e["name"] in PROLOGUE_CHILDREN:
+            assert lo <= e["ts"] and e["ts"] + e["dur"] <= hi + 1, e
+    first_pre = min(e["ts"] for e in events if e["name"] == "pipeline.pre")
+    assert hi <= first_pre + 1
+    # one acquire a slot: S1 and S3-partial of two batches, and finalize
+    slots = sorted(e["args"]["slot"] for e in events
+                   if e["name"] == "dealer.acquire")
+    assert slots == [0, 1, 2, 3, 4]
+    for e in events:
+        if e["name"].startswith("dealer.") and e["name"] != "dealer.acquire":
+            assert e["args"]["count"] >= 1 and e["args"]["key"]
+
+
+def test_minibatch_fit_counts_h2d_bytes_where_they_move(
+        traced_minibatch_fit):
+    sparse, x, res, events = traced_minibatch_fit
+
+    def h2d(name):
+        return sum(e["args"]["h2d_bytes"] for e in events
+                   if e["name"] == name)
+
+    work = _bench_work()
+    requests = [(r.kind, r.shape) for p in res.dealer.slot_plans
+                for r in p.requests]
+    assert h2d("dealer.upload") == work.triple_bytes(requests) > 0
+    assert h2d("fit.upload") == x.size * 8
+    assert h2d("fit.init_centroids") == 2 * 2 * 64 * 8
+    # the sparse exchange puts its two (rows, k) result shares up
+    carriers = {e["name"] for e in events if "h2d_bytes" in e["args"]}
+    assert carriers == {"dealer.upload", "fit.upload",
+                        "fit.init_centroids"} | (
+        {"he.exchange"} if sparse else set())
+    for e in events:
+        if e["name"] == "he.exchange":
+            assert e["args"]["h2d_bytes"] == 2 * e["args"]["n"] \
+                * e["args"]["k"] * 8
