@@ -14,10 +14,11 @@ Provider flavours:
   data-independent — that is WHY an offline phase exists at all.
 * `PooledDealer` — executes a `TriplePlan` ahead of time with ONE stacked RNG
   draw and ONE batched ring op per shape-class (instead of thousands of tiny
-  numpy calls), uploads the pools as device arrays, and serves the online
-  phase with zero host work. Bit-exact against `TrustedDealer` under the same
-  seed: both draw from identical per-class PCG64 streams, and a stacked
-  full-range uint64 draw equals the concatenation of the per-request draws.
+  numpy calls), puts each class's per-request arrays on the device in one
+  call, and serves the online phase with zero host work. Bit-exact against
+  `TrustedDealer` under the same seed: both draw from identical per-class
+  PCG64 streams, and a stacked full-range uint64 draw equals the
+  concatenation of the per-request draws.
 * `StreamingPooledDealer` — the pooled dealer's generation chunked into
   per-iteration *tranches*, double-buffered on a background worker: tranche
   t+1 is generated while iteration t's launches consume tranche t, so peak
@@ -47,6 +48,7 @@ import threading
 import time
 from typing import NamedTuple
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -526,6 +528,30 @@ def _account_offline_plan(plan: TriplePlan, log: CommLog) -> float:
     return modelled_s
 
 
+def _host_row(a: np.ndarray, i: int) -> np.ndarray:
+    """Request i of a stacked (count, ...) host array, as a C-contiguous
+    array (0-d for the seed class). The `_gen_*` arrays' rows are views
+    that already are; only another layout is copied."""
+    row = a[i, ...]
+    return row if row.flags.c_contiguous else np.ascontiguousarray(row)
+
+
+def _put_class(name: str, arrays: tuple, count: int) -> tuple:
+    """Put one shape-class's `count` requests on the device; returns
+    ([(array, ...) per request], bytes put), the arrays in the `_gen_*`
+    order, uint64 on the default device. `dealer.split` cuts the stacked
+    host arrays into per-request row views (list work, no device op);
+    `dealer.upload` puts the whole list with ONE `jax.device_put`, so no
+    request costs a dispatch of its own and the stacked array never exists
+    on the device. `arrays=` on the upload span counts the buffers made."""
+    nbytes = sum(a.nbytes for a in arrays)
+    with _trace.span("dealer.split", key=name, count=count):
+        rows = [tuple(_host_row(a, i) for a in arrays) for i in range(count)]
+    with _trace.span("dealer.upload", key=name, count=count,
+                     h2d_bytes=nbytes, arrays=count * len(arrays)):
+        return jax.device_put(rows), nbytes
+
+
 def _gen_tranche(rngs: dict, counts: dict):
     """Generate one {class key: [per-request device-array tuples]} tranche
     from persistent per-class RNG streams. Because a class's stream is
@@ -534,9 +560,9 @@ def _gen_tranche(rngs: dict, counts: dict):
     the bit-exactness property, chunked.
 
     Each class's three costs are spans of their own: `dealer.draw` (the
-    host draw and ring products), `dealer.upload` (the stacked arrays to
-    the device, with their `h2d_bytes`) and `dealer.split` (one device
-    slice per request and array)."""
+    host draw and ring products), `dealer.split` (host list work: the
+    stacked arrays cut into per-request row views) and `dealer.upload`
+    (the one device put of all of them, with its `h2d_bytes`)."""
     pools: dict[tuple, list] = {}
     nbytes = 0
     for key, count in counts.items():
@@ -545,26 +571,22 @@ def _gen_tranche(rngs: dict, counts: dict):
         name = _key_to_str(key)
         with _trace.span("dealer.draw", key=name, count=count):
             arrays = _gen_class(rngs[key], kind, shape, count)
-        size = sum(a.nbytes for a in arrays)
-        with _trace.span("dealer.upload", key=name, count=count,
-                         h2d_bytes=size):
-            stacked = tuple(jnp.asarray(a) for a in arrays)
-        with _trace.span("dealer.split", key=name, count=count):
-            pools[key] = [tuple(a[i] for a in stacked) for i in range(count)]
+        pools[key], size = _put_class(name, arrays, count)
         nbytes += size
     return pools, nbytes
 
 
 class PooledDealer(_TripleServing):
-    """Executes a `TriplePlan` up front and serves it back with device-array
-    slicing only.
+    """Executes a `TriplePlan` up front and serves it back as a plain list
+    index over per-request device arrays.
 
     Generation batches every shape-class into ONE stacked RNG draw and one
     batched ring op (`np.einsum` over the stacked operands for matmul
-    triples, elementwise `*`/`&` otherwise), then uploads each class pool to
-    the device once. Bit-exact with `TrustedDealer(seed)` serving the same
-    request sequence: per-class streams + the uint64 draw-concatenation
-    property make the stacked draw identical to the per-request draws.
+    triples, elementwise `*`/`&` otherwise), then puts each class's
+    per-request arrays on the device in one call. Bit-exact with
+    `TrustedDealer(seed)` serving the same request sequence: per-class
+    streams + the uint64 draw-concatenation property make the stacked draw
+    identical to the per-request draws.
 
     Serving past the planned count — or requesting a shape-class the plan
     never mentioned — raises `PoolExhaustedError`: the trace and the online
@@ -583,9 +605,9 @@ class PooledDealer(_TripleServing):
         self.n_bin = 0
         self._served: dict[tuple, int] = {}     # class key -> cursor
         counts = plan.class_counts()
-        # one host->device upload per class, then split into per-request
-        # views HERE (still offline) so online serving is a plain list
-        # index — no gather launches on the critical path.
+        # one host->device put per class of its per-request arrays HERE
+        # (still offline), so online serving is a plain list index — no
+        # gather launches on the critical path.
         # `advance`: checkpoint resume — pass the REMAINING plan and the
         # per-class request counts the interrupted run already consumed;
         # each class stream jumps past them before generating.
@@ -834,7 +856,8 @@ class StreamingPooledDealer(_TripleServing):
 
 def _gen_tranche_split(rngs: dict, counts_list: list):
     """Generate several consecutive tranches in ONE merged stacked draw per
-    shape-class, then split the per-request tuples back out per tranche.
+    shape-class (and one device put per class), then hand the per-request
+    device tuples back out per tranche: list slicing only, no device op.
     Stream-identical to generating each tranche separately (the uint64
     draw-concatenation property) — this is what lets one worker wakeup
     amortize over several small slots. Returns [(pools, nbytes), ...]."""
@@ -948,7 +971,10 @@ class SlotDealer:
     generated-but-unconsumed slots alive (backpressure), so peak residency
     is O(window x slot bytes) — independent of n and iters. ``group_bytes``
     merges consecutive small slots into one generation wakeup (still split
-    and served per slot; "auto" targets GROUP_TRANCHE_BYTES).
+    and served per slot; "auto" targets GROUP_TRANCHE_BYTES). A wakeup puts
+    each shape-class's per-request arrays on the device in one call, so a
+    slot's tranche is a list of device arrays ready to serve: handing it
+    out runs no device op.
 
     Bit-exact with ``PooledDealer(concat(slot_plans), seed)`` for any
     acquisition order that consumes each slot's own plan exactly
@@ -1213,11 +1239,8 @@ def _gen_provision_item(states: dict, item: tuple) -> tuple:
         rng.bit_generator.advance(int(start) * _class_words(key))
     kind = key[0]
     shape = key[1:] if kind == "matmul" else key[1]
-    arrays = _gen_class(rng, kind, shape, count)
-    stacked = tuple(jnp.asarray(a) for a in arrays)
-    entries = [tuple(a[i] for a in stacked) for i in range(count)]
-    nbytes = sum(int(a.size) * 8 for a in stacked)
-    return entries, nbytes
+    return _put_class(_key_to_str(key), _gen_class(rng, kind, shape, count),
+                      count)
 
 
 def _run_provision_items(items: list, states: dict, workers: int) -> list:

@@ -32,6 +32,7 @@ from repro.core.channel import (CommLog, FaultyTransport, FrameCorrupt,
                                 decode_frame, encode_frame, session_key)
 from repro.core.fraud import FraudDataset
 from repro.core.kmeans import KMeansConfig, SecureKMeans
+from repro.core.triples import _SLOTS, _key_from_str
 from repro.obs import metrics as _metrics
 from repro.obs import trace as _trace
 from repro.serve import ScoringClient, ScoringServer, ScoringService
@@ -695,7 +696,7 @@ def traced_minibatch_fit(request):
 
 
 def test_minibatch_fit_spans_its_stages(traced_minibatch_fit):
-    sparse, _x, _res, events = traced_minibatch_fit
+    sparse, _x, res, events = traced_minibatch_fit
     names = {e["name"] for e in events}
     assert PROLOGUE_CHILDREN | {
         "fit", "fit.prologue", "fit.finalize", "fit.assemble",
@@ -717,6 +718,13 @@ def test_minibatch_fit_spans_its_stages(traced_minibatch_fit):
     for e in events:
         if e["name"].startswith("dealer.") and e["name"] != "dealer.acquire":
             assert e["args"]["count"] >= 1 and e["args"]["key"]
+    # each class reaches the device in one put of all its requests' arrays
+    uploads = [e["args"] for e in events if e["name"] == "dealer.upload"]
+    for a in uploads:
+        kind = _key_from_str(a["key"])[0]
+        assert a["arrays"] == a["count"] * _SLOTS[kind], a
+    assert sum(a["arrays"] for a in uploads) == sum(
+        _SLOTS[r.kind] for p in res.dealer.slot_plans for r in p.requests)
 
 
 def test_minibatch_fit_counts_h2d_bytes_where_they_move(

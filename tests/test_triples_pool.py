@@ -5,11 +5,14 @@ The load-bearing property: bulk per-class generation (one stacked RNG draw
 + one batched ring op per shape-class) serves the SAME uint64 words as the
 on-demand dealer under the same seed — at the single-triple level, at the
 pjit flat-tensor level, and through a full SecureKMeans.fit for all four
-partition x sparsity combinations."""
+partition x sparsity combinations. Each class reaches the device in ONE put
+of its per-request arrays, never one device op a request."""
+import jax
 import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
+from repro.core import triples
 from repro.core.kmeans import KMeansConfig, SecureKMeans
 from repro.core.triples import (PlanningDealer, PlanRequest, PooledDealer,
                                 PoolExhaustedError, StreamingPooledDealer,
@@ -17,6 +20,7 @@ from repro.core.triples import (PlanningDealer, PlanRequest, PooledDealer,
 from repro.launch.kmeans_step import (materialize_offline,
                                       pooled_offline_arrays,
                                       record_offline_shapes)
+from repro.obs import trace as _trace
 
 RNG = np.random.default_rng(77)
 
@@ -384,3 +388,90 @@ def test_pooled_offline_arrays_match_trusted_dealer():
             np.testing.assert_array_equal(np.asarray(got, np.uint64),
                                           np.asarray(ref, np.uint64))
     assert all(v == 0 for v in dealer.remaining().values())
+
+
+# -- one device put per shape-class ------------------------------------------
+
+PUT_SHAPES = {"matmul": ((5, 3), (3, 2)), "mul": (4, 3), "bin": (2, 7),
+              "rand": (6,), "seed": ()}
+
+
+def _counting_put(monkeypatch):
+    """Count `jax.device_put` calls and the leaves of each; fail on any
+    `jnp.asarray`, the per-array upload the dealer no longer makes."""
+    calls = []
+    real = jax.device_put
+
+    def put(x, *a, **kw):
+        calls.append(len(jax.tree_util.tree_leaves(x)))
+        return real(x, *a, **kw)
+
+    def no_asarray(*a, **kw):
+        raise AssertionError("per-array jnp.asarray in the dealer")
+
+    monkeypatch.setattr(jax, "device_put", put)
+    monkeypatch.setattr(triples.jnp, "asarray", no_asarray)
+    return calls
+
+
+@pytest.mark.parametrize("kind", sorted(PUT_SHAPES))
+def test_put_class_serves_host_rows_word_for_word(kind):
+    """Every served array is uint64 and row i of the host stacked draw, word
+    for word; the traced upload counts the buffers its one put made."""
+    count = 3
+    key = triples._class_key(kind, PUT_SHAPES[kind])
+    shape = key[1:] if kind == "matmul" else key[1]
+    arrays = triples._gen_class(triples._class_rng(31, key), kind, shape,
+                                count)
+    t = _trace.get_tracer()
+    was = t.enabled
+    t.reset()
+    _trace.configure(enabled=True)
+    try:
+        served, nbytes = triples._put_class("k", arrays, count)
+        events = t.events()
+    finally:
+        _trace.configure(enabled=was)
+        t.reset()
+    assert len(served) == count
+    for i, req in enumerate(served):
+        assert len(req) == len(arrays) == triples._SLOTS[kind]
+        for got, host in zip(req, arrays):
+            assert got.dtype == np.uint64
+            assert got.shape == host.shape[1:]
+            np.testing.assert_array_equal(np.asarray(got), host[i])
+    (up,) = [e["args"] for e in events if e["name"] == "dealer.upload"]
+    (split,) = [e["args"] for e in events if e["name"] == "dealer.split"]
+    assert up["arrays"] == count * len(arrays)
+    assert up["h2d_bytes"] == nbytes == sum(a.nbytes for a in arrays)
+    assert up["key"] == split["key"] == "k"
+    assert up["count"] == split["count"] == count
+
+
+def test_gen_tranche_puts_each_class_once(monkeypatch):
+    counts = {triples._class_key(k, PUT_SHAPES[k]): c
+              for k, c in zip(sorted(PUT_SHAPES), (3, 4, 5, 3, 6))}
+    rngs = {key: triples._class_rng(9, key) for key in counts}
+    calls = _counting_put(monkeypatch)
+    pools, _ = triples._gen_tranche(rngs, counts)
+    assert sorted(calls) == sorted(c * triples._SLOTS[key[0]]
+                                   for key, c in counts.items())
+    assert {key: len(v) for key, v in pools.items()} == counts
+
+
+@pytest.mark.parametrize("kind", sorted(PUT_SHAPES))
+def test_provision_item_puts_its_chunk_once(monkeypatch, kind):
+    """A bank chunk takes the same one put, and serves the same words as
+    the tranche path from the same stream offset."""
+    key = triples._class_key(kind, PUT_SHAPES[kind])
+    states = {key: triples._class_rng(5, key).bit_generator.state}
+    want, _ = triples._gen_tranche({key: triples._class_rng(5, key)},
+                                   {key: 7})
+    calls = _counting_put(monkeypatch)
+    entries, nbytes = triples._gen_provision_item(states, (key, 2, 4))
+    assert calls == [4 * triples._SLOTS[kind]]
+    assert nbytes == sum(int(a.size) * 8 for t in entries for a in t)
+    for got, ref in zip(entries, want[key][2:6]):
+        for g, r in zip(got, ref):
+            assert g.dtype == np.uint64
+            np.testing.assert_array_equal(np.asarray(g), np.asarray(r))
