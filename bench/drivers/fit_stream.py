@@ -8,6 +8,14 @@ have passed; no fit starts after that. Fit i is seeded by
 `data.derived_seed(seed, "fit", i)`, so its initial rows, triples and masks
 differ from fit to fit while its work does not.
 
+The parties' inputs are cut from one seeded matrix x as the configuration's
+partition states: vertical, party A holds the columns x[:, :d_a] and party
+B the rest; horizontal, party A holds the rows x[:n_a] and party B the
+rows x[n_a:], every feature of each. The program draws a horizontal fit's
+initial rows from the two parties' rows stacked [A rows; B rows], which is
+x itself, so `reference.init_rows` over x names the same rows in either
+partition and the check is the same for both.
+
 End-to-end numbers:
   fit_rows_per_s                 sum of n * iterations over the summed wall
                                  of the window's fits, each from `fit`
@@ -34,19 +42,71 @@ def reveal(shares, f: int) -> np.ndarray:
     return (s0 + s1).astype(np.int64).astype(np.float64) / float(1 << f)
 
 
-def fit_numbers(mu, ref_mu) -> dict:
+def column_blocks(cfg: dict) -> list:
+    """[(lo, hi)] of each party's columns: vertical, party A's first d_a
+    features and party B's rest; horizontal, both parties hold every
+    feature, one block."""
+    if cfg["partition"] == "vertical":
+        return [(0, cfg["d_a"]), (cfg["d_a"], cfg["d"])]
+    return [(0, cfg["d"])]
+
+
+def fit_numbers(mu, ref_mu, blocks) -> dict:
     """The number the check compares for one fit.
 
-    centroid_err  worst |centroid - the reference's centroid| over every
-                  cluster and feature. The reference runs plain Lloyd from
-                  the same initial rows, so a row put in the wrong cluster
-                  and a wrong update both show. The protocol's own SecureML
-                  truncation failures (probability about |x| / 2^64 per
-                  truncated lane) put about one row in 10^5 in a farther
-                  cluster, which moves a centroid by about 1 / (rows in
-                  the cluster); rounding at f=20 moves it by less.
+    centroid_median_err  the largest, over clusters and the parties' column
+                  blocks, of the median over the block's features of
+                  |centroid - the reference's centroid|. The reference
+                  runs plain Lloyd from the same initial rows. A wrong
+                  update, rows left out of it or many rows in the wrong
+                  cluster move every feature of a centroid; an error in
+                  one party's columns shows in that party's block; the
+                  control's fixed point errs on every feature. The median
+                  is steady where the largest error is not: a SecureML
+                  truncation failure (about one row in 10^5) puts a row
+                  in a farther cluster and moves each feature of its
+                  centroids by |x_j - mu_j| / (rows in the cluster), which
+                  in a cluster of a few thousand rows takes the largest
+                  feature's error near the control's; a failed lane of the
+                  update errs on one element alone.
     """
-    return {"centroid_err": float(np.abs(mu - ref_mu).max())}
+    err = np.abs(mu - ref_mu)
+    return {"centroid_median_err": float(max(
+        np.median(err[:, lo:hi], axis=1).max() for lo, hi in blocks))}
+
+
+def batch_rows(cfg: dict) -> list:
+    """[(party A's rows, party B's rows)] of each minibatch, in the order a
+    fit runs them.
+
+    Vertical: both parties hold columns of the same rows, cut into shared
+    chunks of `batch_size` rows, the last one shorter. Horizontal: each
+    party's rows are cut into the same number of batches,
+    B = max(1, min(ceil(n / batch_size), n_a, n_b)), contiguous and as near
+    equal as can be (the first n_p mod B of party p's chunks one row
+    longer), so each batch holds a chunk of each party's rows."""
+    n, bs = cfg["n"], cfg["batch_size"]
+    if cfg["partition"] == "vertical":
+        return [(min(bs, n - lo),) * 2 for lo in range(0, n, bs)]
+    n_a = cfg["n_a"]
+    n_b = n - n_a
+    b = max(1, min(-(-n // bs), n_a, n_b))
+    return [(n_a // b + (i < n_a % b), n_b // b + (i < n_b % b))
+            for i in range(b)]
+
+
+def batch_shapes(cfg: dict) -> list:
+    """[(party A's (rows, features), party B's)] of each minibatch, as the
+    fit's batch programs take them."""
+    d = cfg["d"]
+    if cfg["partition"] == "vertical":
+        return [((r, cfg["d_a"]), (r, d - cfg["d_a"]))
+                for r, _ in batch_rows(cfg)]
+    return [((ra, d), (rb, d)) for ra, rb in batch_rows(cfg)]
+
+
+# the window's fits whose shapes set-up warms: far more than a window holds
+WARM_FITS = 10_000
 
 
 class Driver:
@@ -73,11 +133,44 @@ class Driver:
         rng = data.rng_for(self.seed, "data")
         self.x = data.blobs(rng, data.centres(rng, c["k"], c["d"]), c["n"],
                             c["noise"], c["sparsity"], c.get("onehot_groups"))
-        self.x_a = np.ascontiguousarray(self.x[:, :c["d_a"]])
-        self.x_b = np.ascontiguousarray(self.x[:, c["d_a"]:])
-        t0 = time.monotonic()
-        self._fit(data.derived_seed(self.seed, "warmup", 0))
-        self.log(f"warmup fit wall_s={time.monotonic() - t0:.3f}")
+        if c["partition"] == "vertical":
+            self.x_a = np.ascontiguousarray(self.x[:, :c["d_a"]])
+            self.x_b = np.ascontiguousarray(self.x[:, c["d_a"]:])
+        else:
+            self.x_a = np.ascontiguousarray(self.x[:c["n_a"]])
+            self.x_b = np.ascontiguousarray(self.x[c["n_a"]:])
+        for fit_seed in self.warmup_seeds():
+            t0 = time.monotonic()
+            self._fit(fit_seed)
+            self.log(f"warmup fit wall_s={time.monotonic() - t0:.3f}")
+
+    def warmup_seeds(self) -> list:
+        """The seeds of set-up's warm-up fits: one fit, and in a horizontal
+        fit one more for each split of the initial rows between the parties
+        (how many of them lie among party A's n_a) that the first
+        WARM_FITS fits of the window take and the warm-up has not. The
+        program puts each party's initial rows together in a small program
+        whose shape is that split, so a split not warmed would compile
+        inside the window."""
+        c = self.cfg
+        seeds = [data.derived_seed(self.seed, "warmup", 0)]
+        if c["partition"] == "vertical":
+            return seeds
+
+        def split(fit_seed):
+            init = reference.init_rows(fit_seed, c["n"], c["k"])
+            return int((init < c["n_a"]).sum())
+        possible = set(range(max(0, c["k"] - (c["n"] - c["n_a"])),
+                             min(c["k"], c["n_a"]) + 1))
+        splits = {split(seeds[0])}
+        for i in range(WARM_FITS):
+            if splits == possible:
+                break
+            fit_seed = data.derived_seed(self.seed, "fit", i)
+            if (s := split(fit_seed)) not in splits:
+                splits.add(s)
+                seeds.append(fit_seed)
+        return seeds
 
     def _fit(self, fit_seed: int):
         from repro.core.kmeans import SecureKMeans
@@ -133,34 +226,33 @@ class Driver:
                     sum(f["online_bytes"] for f in self.fits) / row_iters}
 
     def _batch_programs(self):
+        """({(shape_a, shape_b): programs} of the batch geometries a fit
+        runs, the batches' shapes in the order the fit runs them, and the
+        finalize program)."""
         from repro.launch import kmeans_step as K
         c = self.cfg
-        rows = sorted({min(c["batch_size"], c["n"] - lo)
-                       for lo in range(0, c["n"], c["batch_size"])})
-        progs = {r: K.fit_batch_programs(c["partition"], c["sparse"],
-                                         (r, c["d_a"]),
-                                         (r, c["d"] - c["d_a"]), c["k"])
-                 for r in rows}
+        shapes = batch_shapes(c)
+        progs = {s: K.fit_batch_programs(c["partition"], c["sparse"], *s,
+                                         c["k"])
+                 for s in sorted(set(shapes))}
         fin = K.finalize_program(c["k"], c["d"], c["n"])
-        return progs, fin
+        return progs, shapes, fin
 
     def counters(self) -> dict:
-        c = self.cfg
-        progs, fin = self._batch_programs()
+        progs, shapes, fin = self._batch_programs()
         per_iter = work.triple_bytes(fin.requests)
-        for lo in range(0, c["n"], c["batch_size"]):
-            p = progs[min(c["batch_size"], c["n"] - lo)]
-            per_iter += work.triple_bytes(p.s1_requests) \
-                + work.triple_bytes(p.s3p_requests)
+        for s in shapes:
+            per_iter += work.triple_bytes(progs[s].s1_requests) \
+                + work.triple_bytes(progs[s].s3p_requests)
         return {
             "fits_wall_s": sum(f["wall_s"] for f in self.fits),
             "dealer_wait_s": sum(f["dealer_wait_s"] for f in self.fits),
             "dealer_gen_s": sum(f["dealer_gen_s"] for f in self.fits),
-            "triple_bytes_per_row_iter": per_iter / c["n"],
+            "triple_bytes_per_row_iter": per_iter / self.cfg["n"],
         }
 
     def hlo_texts(self) -> list:
-        progs, fin = self._batch_programs()
+        progs, _shapes, fin = self._batch_programs()
         out = [fin.fn.as_text()]
         for p in progs.values():
             out += [p.s1.as_text(), p.s3p.as_text()]
@@ -170,18 +262,23 @@ class Driver:
     def check(self, control: bool = False):
         """(attempted, failed, {number: worst value over the fits})."""
         c = self.cfg
-        worst = {"centroid_err": 0.0}
+        blocks = column_blocks(c)
+        worst = {"centroid_median_err": 0.0}
         for fit in self.fits:
             init = reference.init_rows(fit["seed"], c["n"], c["k"])
-            _hist, ref_mu, _labels = reference.lloyd(self.x, init,
-                                                     c["iters"])
+            _hist, ref_mu, labels = reference.lloyd(self.x, init,
+                                                    c["iters"])
             mu = fit["mu"]
             if control:
                 _h, mu, _l = reference.lloyd_fixed(self.x, init, c["iters"])
-            nums = fit_numbers(mu, ref_mu)
+            nums = fit_numbers(mu, ref_mu, blocks)
             for k_, v in nums.items():
                 worst[k_] = max(worst[k_], v)
+            # the largest single error and the clusters' sizes are logged
+            # beside it, not compared
             self.log(f"check fit seed={fit['seed']} " + " ".join(
-                f"{k_}={v:.6g}" for k_, v in nums.items()))
+                f"{k_}={v:.6g}" for k_, v in nums.items())
+                + f" centroid_err={np.abs(mu - ref_mu).max():.6g} rows="
+                + ",".join(map(str, np.bincount(labels, minlength=c["k"]))))
         return len(self.fits) + len(self.failures), len(self.failures), \
             worst

@@ -1,7 +1,8 @@
 """The dealer's device side against the fits' wall: the summed wall of the
-program's `dealer.upload` spans (the stacked triples put on the device)
-and `dealer.split` spans (one device slice per request and array) over
-the fits' summed wall, each as the host calls it. With
+program's `dealer.upload` spans (one `jax.device_put` of a triple class's
+per-request arrays) and `dealer.split` spans (the host list work that cuts
+the class's stacked draw into those per-request arrays) over the fits'
+summed wall, each as the host calls it. With
 `fit.dealer_draw_share` it splits `fit.dealer_gen_share`. Nothing is read
 where the program has no such span."""
 LAYER = "offline dealer (core/triples.py)"

@@ -68,7 +68,17 @@ def workload(bench: dict, name: str) -> dict:
     raise SpecError(f"BENCHMARK.json has no workload {name!r}")
 
 
+# partition -> (the key that says where party A's share ends, the size it
+# cuts, the other partition's key, which such a configuration must not carry)
+SPLIT = {"vertical": ("d_a", "d", "n_a"),
+         "horizontal": ("n_a", "n", "d_a")}
+
+
 def load_config(root: str, bench: dict, name: str) -> dict:
+    """A configuration file, refused unless it states its partition and the
+    split that goes with it: vertical, party A holds the first `d_a` of the
+    `d` features of every row; horizontal, party A holds the first `n_a` of
+    the `n` rows, every feature of each."""
     _name(name, "config")
     entry = next((c for c in bench["configs"] if c.get("name") == name),
                  None)
@@ -78,12 +88,20 @@ def load_config(root: str, bench: dict, name: str) -> dict:
     if not isinstance(cfg, dict) or cfg.get("name") != name:
         raise SpecError(f"config file {entry['file']} does not hold "
                         f"config {name!r}")
-    for key in ("n", "d", "d_a", "k", "iters", "ring_bits", "frac_bits"):
+    partition = cfg.get("partition")
+    if partition not in SPLIT:
+        raise SpecError(f"config {name}: 'partition' must be one of "
+                        f"{sorted(SPLIT)}, not {partition!r}")
+    split, whole, other = SPLIT[partition]
+    for key in ("n", "d", "k", "iters", "ring_bits", "frac_bits", split):
         if not isinstance(cfg.get(key), int) or cfg[key] < 1:
             raise SpecError(f"config {name}: {key!r} must be a positive "
                             "integer")
-    if not 0 < cfg["d_a"] < cfg["d"]:
-        raise SpecError(f"config {name}: need 0 < d_a < d")
+    if not cfg[split] < cfg[whole]:
+        raise SpecError(f"config {name}: need 0 < {split} < {whole}")
+    if other in cfg:
+        raise SpecError(f"config {name}: a {partition} configuration "
+                        f"splits at {split!r} and carries no {other!r}")
     return cfg
 
 
@@ -184,12 +202,16 @@ def per_layer_for(bench: dict, cell: dict) -> list:
 def rehearsal_sizes(cfg: dict, traffic: dict):
     """A tiny copy of a cell for a CPU rehearsal: the same paths (a last
     batch shorter than the others included), widths cut to what the CPU
-    compiles in seconds."""
+    compiles in seconds. A horizontal split keeps its share of the rows."""
     cfg = dict(cfg)
+    n = cfg["n"]
     scale = {"n": 2000, "d": 64, "d_a": 32, "k": 4, "batch_size": 1024}
     for key, v in scale.items():
         if key in cfg and cfg[key] is not None:
             cfg[key] = min(cfg[key], v)
+    if "n_a" in cfg:
+        cfg["n_a"] = min(max(1, round(cfg["n_a"] * cfg["n"] / n)),
+                         cfg["n"] - 1)
     if cfg.get("onehot_groups"):
         cfg["onehot_groups"] = [4] * (cfg["d"] // 4)
     return cfg, dict(traffic)

@@ -12,7 +12,7 @@ leaves half the batch out, so the mean is taken over the rest; an S1 launch
 that alters the assignment of every 64th row where it is made (one row
 alone is within the protocol's own rate of truncation failures, which put
 about one row in 10^5 in a farther cluster). Each test runs in both fit
-cells.
+cells, and in a horizontally partitioned copy of the dense one.
 
 No cell spans chips, so the fault of an exchange between chips left out
 cannot occur.
@@ -36,23 +36,24 @@ class _Null:
         return False
 
 
-def _driver(workload, seconds):
-    bench = spec.load_benchmark(ROOT)
+def _driver(root, workload, seconds):
+    bench = spec.load_benchmark(root)
     cell = spec.workload(bench, workload)
     cfg, traffic = spec.rehearsal_sizes(
-        spec.load_config(ROOT, bench, cell["config"]),
-        spec.load_traffic(ROOT, cell["traffic"]))
-    mod = spec.load_driver(ROOT, traffic["kind"])
+        spec.load_config(root, bench, cell["config"]),
+        spec.load_traffic(root, cell["traffic"]))
+    mod = spec.load_driver(root, traffic["kind"])
     d = mod.Driver(cfg, traffic, 20260917, seconds, log=lambda m: None,
                    annotate=lambda name: _Null())
     d.setup()
+    d.root, d.workload = root, workload
     return d
 
 
-def _judge(workload, d, control=False):
+def _judge(d, control=False):
     attempted, failed, checks = d.check(control=control)
     return spec.judge(attempted, failed, checks,
-                      spec.load_limits(ROOT, workload))
+                      spec.load_limits(d.root, d.workload))
 
 
 def _roll_rows(c, every: int):
@@ -61,11 +62,16 @@ def _roll_rows(c, every: int):
     return c.at[::every].set(jnp.roll(c[::every], 1, axis=1))
 
 
-@pytest.fixture(scope="module", params=["fit.dense", "fit.sparse"])
+@pytest.fixture(scope="module",
+                params=["fit.dense", "fit.sparse", "fit.horizontal"])
 def fit_driver(request):
-    d = _driver(request.param, 0.5)
-    d.workload = request.param
-    return d
+    """The driver of each fit cell at rehearsal size; `fit.horizontal` is a
+    copy of `fit.dense`'s deployment with its rows split 3:1 between the
+    parties, from a benchmark tree that adds it (conftest.py)."""
+    root = ROOT
+    if request.param == "fit.horizontal":
+        root = request.getfixturevalue("horizontal_tree")
+    return _driver(root, request.param, 0.5)
 
 
 def _fit_window(d):
@@ -77,13 +83,13 @@ def _fit_window(d):
 
 def test_fit_sound_window_is_correct(fit_driver):
     _fit_window(fit_driver)
-    correct, compared = _judge(fit_driver.workload, fit_driver)
+    correct, compared = _judge(fit_driver)
     assert correct, compared
 
 
 def test_fit_control_is_not_correct(fit_driver):
     _fit_window(fit_driver)
-    correct, compared = _judge(fit_driver.workload, fit_driver, control=True)
+    correct, compared = _judge(fit_driver, control=True)
     assert not correct, compared
 
 
@@ -130,5 +136,5 @@ def test_fit_fault_is_not_correct(fit_driver, monkeypatch, fault):
     from repro.launch import kmeans_step as K
     fault(K, monkeypatch)
     _fit_window(fit_driver)
-    correct, compared = _judge(fit_driver.workload, fit_driver)
+    correct, compared = _judge(fit_driver)
     assert not correct, compared

@@ -167,3 +167,77 @@ def test_a_new_cell_needs_only_new_files(tree):
     assert [m["name"] for m in spec.per_layer_for(b, cell)] == ["fit.count"]
     assert spec.load_metric(str(tree), "fit.count").read(
         spec.Run(cell, {}, {}, None, {}, {"fits": 3}, None, [])) == 3
+
+
+def _without(cfg, key):
+    return {k: v for k, v in cfg.items() if k != key}
+
+
+HORIZONTAL = {"partition": "horizontal", "n": 2000, "n_a": 1500}
+PARTITION_CASES = {
+    "horizontal_loads": (HORIZONTAL, ["d_a"], None),
+    "horizontal_without_n_a": (HORIZONTAL, ["d_a", "n_a"], "'n_a'"),
+    "horizontal_n_a_zero": (dict(HORIZONTAL, n_a=0), ["d_a"], "'n_a'"),
+    "horizontal_n_a_all_rows": (dict(HORIZONTAL, n_a=2000), ["d_a"],
+                                "n_a < n"),
+    "horizontal_n_a_past_n": (dict(HORIZONTAL, n_a=2001), ["d_a"],
+                              "n_a < n"),
+    "horizontal_with_d_a": (HORIZONTAL, [], "'d_a'"),
+    "unknown_partition": ({"partition": "diagonal"}, [], "'partition'"),
+    "no_partition": ({}, ["partition"], "'partition'"),
+    "vertical_d_a_all_columns": ({"d_a": 432}, [], "d_a < d"),
+    "vertical_with_n_a": ({"n_a": 10}, [], "'n_a'"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PARTITION_CASES))
+def test_a_config_states_its_partition_and_split(tree, case):
+    """A configuration loads only with the split its partition needs:
+    vertical `0 < d_a < d`, horizontal `0 < n_a < n` and no `d_a`; each
+    other case is refused with an error that names the key."""
+    update, drop, refused = PARTITION_CASES[case]
+    b = spec.load_benchmark(str(tree))
+    path = tree / "bench/configs/ieee-cis-vertical.json"
+    cfg = dict(json.loads(path.read_text()), **update)
+    for key in drop:
+        cfg = _without(cfg, key)
+    path.write_text(json.dumps(cfg))
+    if refused is None:
+        got = spec.load_config(str(tree), b, "ieee-cis-vertical")
+        assert (got["partition"], got["n_a"]) == ("horizontal", 1500)
+    else:
+        with pytest.raises(spec.SpecError, match=refused):
+            spec.load_config(str(tree), b, "ieee-cis-vertical")
+
+
+def test_a_new_horizontal_cell_needs_only_new_files(horizontal_tree):
+    """A horizontal cell added as a later PR would add it (a configuration
+    file, entries in BENCHMARK.json, a limits file) finds every file it
+    needs, and its CPU rehearsal keeps the parties' share of the rows."""
+    b = spec.load_benchmark(horizontal_tree)
+    cell = spec.workload(b, "fit.horizontal")
+    cfg = spec.load_config(horizontal_tree, b, cell["config"])
+    assert (cfg["partition"], cfg["n"], cfg["n_a"]) == \
+        ("horizontal", 144233, 108174)
+    traffic = spec.load_traffic(horizontal_tree, cell["traffic"])
+    assert hasattr(spec.load_driver(horizontal_tree, traffic["kind"]),
+                   "Driver")
+    assert spec.load_limits(horizontal_tree, cell["name"])
+    assert spec.end_to_end_for(b, cell)
+    for m in spec.per_layer_for(b, cell):
+        assert spec.load_metric(horizontal_tree, m["name"]).MOVES \
+            == m["moves"]
+    small, _ = spec.rehearsal_sizes(cfg, traffic)
+    assert (small["n"], small["n_a"], small["d"]) == (2000, 1500, 64)
+    assert "d_a" not in small
+
+
+@pytest.mark.parametrize("n,n_a,want", [(144233, 108174, 1500),
+                                        (590540, 439670, 1489),
+                                        (10 ** 6, 1, 1),
+                                        (10 ** 6, 10 ** 6 - 1, 1999),
+                                        (1500, 1000, 1000)])
+def test_rehearsal_keeps_the_row_split(n, n_a, want):
+    cfg = {"partition": "horizontal", "n": n, "n_a": n_a, "d": 392}
+    small, _ = spec.rehearsal_sizes(cfg, {})
+    assert small["n_a"] == want and 0 < small["n_a"] < small["n"]
