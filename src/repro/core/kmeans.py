@@ -27,9 +27,11 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import time
 from typing import Literal
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -38,7 +40,7 @@ from repro.core import protocol as P
 from repro.core import ring
 from repro.core.channel import CommLog, NetModel
 from repro.core.he import OU_COST_S, SimulatedPHE
-from repro.core.sharing import AShare, rec, rec_real, share
+from repro.core.sharing import AShare, rec, rec_real, share, share_np
 from repro.core.sparse import CSRMatrix, secure_sparse_matmul
 from repro.core.triples import (BankSlotDealer, PlanningDealer, PooledDealer,
                                 SlotDealer, StreamingPooledDealer, TriplePlan,
@@ -1309,12 +1311,17 @@ class SecureKMeans:
             mask = idx < x_a.shape[0]
             rows_a = _encode_np(x_a[idx[mask]], cfg.f)
             rows_b = _encode_np(x_b[idx[~mask] - x_a.shape[0]], cfg.f)
-            sh = _share_cat(ctx, rng, [rows_a, rows_b], axis=0)
-            # restore the jointly-negotiated index order (A rows then B rows
-            # were concatenated; undo that permutation)
-            perm = np.concatenate([np.where(mask)[0], np.where(~mask)[0]])
-            inv = np.argsort(perm)
-            sh = AShare(sh.s0[inv], sh.s1[inv])
+            # each party shares its rows (A's draws first); the shares are
+            # joined and put back in the jointly-negotiated index order on
+            # the host, so the device gets one (k, d) array per share and
+            # nothing compiles by how many of the k rows lie in A
+            with _trace.span("fit.join", rows_a=len(rows_a),
+                             rows_b=len(rows_b)):
+                parts = [share_np(rows_a, rng), share_np(rows_b, rng)]
+                inv = np.argsort(np.concatenate([np.flatnonzero(mask),
+                                                 np.flatnonzero(~mask)]))
+                s0, s1 = (np.concatenate(s)[inv] for s in zip(*parts))
+            sh = AShare(jnp.asarray(s0), jnp.asarray(s1))
         ctx.log.send(2 * ring.nbytes(sh.shape), tag="init", phase="online")
         return sh
 
@@ -1604,11 +1611,22 @@ def _assemble_assignment(partition: str, c_parts: list,
     if partition == "vertical":
         return AShare(jnp.concatenate([p.s0 for p in c_parts], 0),
                       jnp.concatenate([p.s1 for p in c_parts], 0))
-    a0 = [p.s0[:b["a_rows"]] for p, b in zip(c_parts, batches)]
-    a1 = [p.s1[:b["a_rows"]] for p, b in zip(c_parts, batches)]
-    b0 = [p.s0[b["a_rows"]:] for p, b in zip(c_parts, batches)]
-    b1 = [p.s1[b["a_rows"]:] for p, b in zip(c_parts, batches)]
-    return AShare(jnp.concatenate(a0 + b0, 0), jnp.concatenate(a1 + b1, 0))
+    a_rows = tuple(b["a_rows"] for b in batches)
+    rows = sum(p.shape[0] for p in c_parts)
+    with _trace.span("fit.join", rows_a=sum(a_rows),
+                     rows_b=rows - sum(a_rows)):
+        return AShare(*_horizontal_order(a_rows, [p.s0 for p in c_parts],
+                                         [p.s1 for p in c_parts]))
+
+
+@functools.partial(jax.jit, static_argnums=0)
+def _horizontal_order(a_rows: tuple, s0_parts: list, s1_parts: list):
+    """Both shares' per-batch [A chunk; B chunk] rows in [all A rows; all B
+    rows] order: one program per batch geometry, no per-batch slices."""
+    def join(parts):
+        return jnp.concatenate([p[:a] for p, a in zip(parts, a_rows)]
+                               + [p[a:] for p, a in zip(parts, a_rows)], 0)
+    return join(s0_parts), join(s1_parts)
 
 
 def _encode_np(x: np.ndarray, f: int) -> np.ndarray:

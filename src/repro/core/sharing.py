@@ -41,11 +41,16 @@ class BShare(NamedTuple):
         return self.b0.shape
 
 
-def share(x, rng: np.random.Generator) -> AShare:
-    """Shr(x): split a ring tensor into two uniform shares."""
+def share_np(x, rng: np.random.Generator) -> tuple:
+    """Shr(x) on the host: the two uniform shares (s0, s1) as numpy words."""
     x = np.asarray(x, np.uint64)
     s0 = ring.rand_np(rng, x.shape)
-    s1 = x - s0  # uint64 wraparound == mod 2^64
+    return s0, x - s0  # uint64 wraparound == mod 2^64
+
+
+def share(x, rng: np.random.Generator) -> AShare:
+    """Shr(x): split a ring tensor into two uniform shares."""
+    s0, s1 = share_np(x, rng)
     return AShare(jnp.asarray(s0), jnp.asarray(s1))
 
 

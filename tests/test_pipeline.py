@@ -324,3 +324,205 @@ def test_assemble_assignment_horizontal_order():
     c = _assemble_assignment("horizontal", parts, batches)
     np.testing.assert_array_equal(np.asarray(c.s0, np.uint64).ravel(),
                                   [1, 2, 3, 10, 11])
+
+
+# ---------------------------------------------------------------------------
+# a horizontal consortium at a skewed split (the fraud-horizontal-skew
+# deployment at a small size): minibatch, streamed, pipelined
+# ---------------------------------------------------------------------------
+
+def _init_split(seed, n, n_a, k):
+    """How many of a fit's k initial rows lie among party A's n_a."""
+    idx = np.random.default_rng(seed).choice(n, k, replace=False)
+    return int((idx < n_a).sum())
+
+
+# the finalize division errs by at most 2^(m - s - f) |x| (m = ceil(log2 n),
+# s = m // 2), under 3e-4 for these rows and in practice about 1.5e-5; a
+# secure fit encoded at f=10 misses it
+# (test_horizontal_skewed_fit_f10_misses_tolerance)
+CENTROID_ATOL = 1e-4
+
+# (n, n_a, d, k, batch_size, seed): a 2.9:1 split; a split where party B
+# has fewer rows than ceil(n / batch_size), so the batches are clamped to
+# n_b; and k=3 with a seed for each split 0..3 of the initial rows
+SKEWED_FITS = [(2000, 1489, 16, 2, 256, 5), (2000, 1995, 16, 2, 256, 82)] \
+    + [(800, 596, 8, 3, 128, s) for s in (13, 4, 1, 6)]
+
+
+@pytest.mark.parametrize("n,n_a,d,k,batch_size,seed", SKEWED_FITS,
+                         ids=["2.9to1", "b_clamped", "k3_split0",
+                              "k3_split1", "k3_split2", "k3_split3"])
+def test_horizontal_skewed_fit_matches_plaintext(n, n_a, d, k, batch_size,
+                                                 seed):
+    """A secure horizontal minibatch fit at a skewed split agrees with
+    plain Lloyd on the stacked [A rows; B rows] from the same initial
+    rows."""
+    from repro.core.kmeans import plaintext_kmeans
+    x = _blobs(n, d, k, seed=11)
+    bounds = _minibatch_bounds("horizontal", n_a, n - n_a, batch_size)
+    assert len(bounds) == min(-(-n // batch_size), n - n_a)
+    cfg = KMeansConfig(k=k, iters=1, seed=seed, partition="horizontal",
+                       backend="xla", offline="streamed",
+                       batch_size=batch_size, pipeline=True)
+    res = SecureKMeans(cfg).fit(x[:n_a], x[n_a:])
+    mu_ref, lab_ref = plaintext_kmeans(x, k, 1, seed=seed)
+    assert (res.labels_plain() == lab_ref).mean() > 0.99
+    np.testing.assert_allclose(res.centroids_plain(), mu_ref, rtol=0,
+                               atol=CENTROID_ATOL)
+    if k == 3:
+        assert _init_split(seed, n, n_a, k) == SKEWED_FITS.index(
+            (n, n_a, d, k, batch_size, seed)) - 2
+
+
+@pytest.mark.parametrize("f", [20, 10])
+def test_horizontal_skewed_fit_f10_misses_tolerance(f):
+    """CENTROID_ATOL is tight enough to fail a fit encoded at f=10: the
+    2.9:1 case, fitted whole-batch (the minibatch programs are compiled for
+    f=20 alone), agrees with plain Lloyd within it at f=20 and misses it
+    at f=10."""
+    from repro.core.kmeans import plaintext_kmeans
+    n, n_a, d, k, _batch_size, seed = SKEWED_FITS[0]
+    x = _blobs(n, d, k, seed=11)
+    cfg = KMeansConfig(k=k, iters=1, seed=seed, partition="horizontal",
+                       backend="xla", f=f)
+    res = SecureKMeans(cfg).fit(x[:n_a], x[n_a:])
+    mu_ref, _ = plaintext_kmeans(x, k, 1, seed=seed)
+    close = np.abs(res.centroids_plain(f) - mu_ref).max() <= CENTROID_ATOL
+    assert close == (f == 20)
+
+
+def _init_centroids_before(rng, x_a, x_b, k, f):
+    """The horizontal initial centroids as the program made them before it
+    joined the parties' rows on the host: each party's shares on the
+    device, joined by a concatenation shaped by the split, then permuted
+    back to the negotiated order by a gather."""
+    import jax.numpy as jnp
+
+    from repro.core.kmeans import _encode_np
+    from repro.core.sharing import AShare, share
+    idx = rng.choice(x_a.shape[0] + x_b.shape[0], k, replace=False)
+    mask = idx < x_a.shape[0]
+    parts = [share(_encode_np(x_a[idx[mask]], f), rng),
+             share(_encode_np(x_b[idx[~mask] - x_a.shape[0]], f), rng)]
+    s0 = jnp.concatenate([p.s0 for p in parts], 0)
+    s1 = jnp.concatenate([p.s1 for p in parts], 0)
+    inv = np.argsort(np.concatenate([np.where(mask)[0],
+                                     np.where(~mask)[0]]))
+    return AShare(s0[inv], s1[inv])
+
+
+@pytest.mark.parametrize("k,seeds", [(2, (10, 2, 1)), (3, (13, 4, 1, 8))],
+                         ids=["k2", "k3"])
+def test_horizontal_init_centroids_words_unchanged(k, seeds):
+    """For every split 0..k of the initial rows, the host-side join gives
+    the share words the device-side join gave, from the same draws."""
+    from repro.core import protocol as P
+    n, n_a = 40, 30
+    x = _blobs(n, 4, k, seed=3)
+    for split, seed in enumerate(seeds):
+        assert _init_split(seed, n, n_a, k) == split
+        cfg = KMeansConfig(k=k, iters=1, seed=seed, partition="horizontal",
+                           backend="xla")
+        got = SecureKMeans(cfg)._init_centroids(
+            P.make_ctx(seed, backend="xla"), np.random.default_rng(seed),
+            x[:n_a], x[n_a:])
+        want = _init_centroids_before(np.random.default_rng(seed),
+                                      x[:n_a], x[n_a:], k, cfg.f)
+        for s in ("s0", "s1"):
+            np.testing.assert_array_equal(
+                np.asarray(getattr(got, s), np.uint64),
+                np.asarray(getattr(want, s), np.uint64))
+
+
+def _assemble_before(c_parts, batches):
+    """The horizontal reassembly as four eager device slices a batch."""
+    import jax.numpy as jnp
+
+    from repro.core.sharing import AShare
+    a0 = [p.s0[:b["a_rows"]] for p, b in zip(c_parts, batches)]
+    a1 = [p.s1[:b["a_rows"]] for p, b in zip(c_parts, batches)]
+    b0 = [p.s0[b["a_rows"]:] for p, b in zip(c_parts, batches)]
+    b1 = [p.s1[b["a_rows"]:] for p, b in zip(c_parts, batches)]
+    return AShare(jnp.concatenate(a0 + b0, 0), jnp.concatenate(a1 + b1, 0))
+
+
+@pytest.mark.parametrize("n_a,n_b,batch_size",
+                         [(1489, 511, 256), (1995, 5, 256), (7, 93, 10),
+                          (300, 300, 1000)])
+def test_horizontal_assemble_words_unchanged(n_a, n_b, batch_size):
+    """The one-program reassembly gives the words the per-batch slices
+    gave, on skewed geometries."""
+    import jax.numpy as jnp
+
+    from repro.core.sharing import AShare
+    rng = np.random.default_rng(n_a)
+    bounds = _minibatch_bounds("horizontal", n_a, n_b, batch_size)
+    batches = [{"a_rows": ahi - alo} for (alo, ahi), _ in bounds]
+    parts = [AShare(*(jnp.asarray(rng.integers(
+        0, 2 ** 64, (ahi - alo + bhi - blo, 3), dtype=np.uint64))
+        for _ in range(2))) for (alo, ahi), (blo, bhi) in bounds]
+    got = _assemble_assignment("horizontal", parts, batches)
+    want = _assemble_before(parts, batches)
+    for s in ("s0", "s1"):
+        np.testing.assert_array_equal(np.asarray(getattr(got, s), np.uint64),
+                                      np.asarray(getattr(want, s), np.uint64))
+
+
+def test_horizontal_fits_compile_nothing_by_split():
+    """After one warm-up fit, fits whose initial rows split otherwise
+    between the parties compile nothing."""
+    import jax
+    n, n_a = 200, 149
+    x = _blobs(n, 8, 2, seed=7)
+
+    def fit(seed):
+        cfg = KMeansConfig(k=2, iters=1, seed=seed, partition="horizontal",
+                           backend="xla", offline="streamed", batch_size=64,
+                           pipeline=True)
+        res = SecureKMeans(cfg).fit(x[:n_a], x[n_a:])
+        res.assignment.s0.block_until_ready()
+    assert [_init_split(s, n, n_a, 2) for s in (1, 2, 10)] == [2, 1, 0]
+    fit(1)
+    compiles = []
+
+    def count(event, _secs, **_kw):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+    jax.monitoring.register_event_duration_secs_listener(count)
+    try:
+        fit(2)
+        fit(10)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(count)
+    assert compiles == []
+
+
+def test_horizontal_join_spans_in_a_traced_fit():
+    """A traced horizontal minibatch fit opens `fit.join` twice, inside
+    `fit.init_centroids` (the k initial rows) and inside `fit.assemble`
+    (every row of each party)."""
+    from repro.obs import trace as obs_trace
+    n, n_a, d, k, batch_size, seed = SKEWED_FITS[0]
+    x = _blobs(n, d, k, seed=11)
+    cfg = KMeansConfig(k=k, iters=1, seed=seed, partition="horizontal",
+                       backend="xla", offline="streamed",
+                       batch_size=batch_size, pipeline=True)
+    tracer = obs_trace.get_tracer()
+    tracer.reset()
+    obs_trace.configure(enabled=True)
+    try:
+        res = SecureKMeans(cfg).fit(x[:n_a], x[n_a:])
+        res.assignment.s0.block_until_ready()
+    finally:
+        obs_trace.configure(enabled=False)
+    ev = [e for e in tracer.events() if e.get("ph") == "X"]
+    joins = [e for e in ev if e["name"] == "fit.join"]
+    assert len(joins) == 2
+    assert sum(joins[0]["args"][r] for r in ("rows_a", "rows_b")) == k
+    assert (joins[1]["args"]["rows_a"], joins[1]["args"]["rows_b"]) == \
+        (n_a, n - n_a)
+    for join, outer in zip(joins, ("fit.init_centroids", "fit.assemble")):
+        o = next(e for e in ev if e["name"] == outer)
+        assert o["ts"] <= join["ts"] and \
+            join["ts"] + join["dur"] <= o["ts"] + o["dur"]
